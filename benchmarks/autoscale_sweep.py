@@ -51,6 +51,7 @@ from repro.core.elastic import AutoscalePolicy, always_on_fleet_idle_kj
 from repro.cluster.node import make_scenario_cluster
 from repro.cluster.simulator import run_scenario
 from repro.cluster.workload import PoissonArrivals
+from repro.device import enable_compile_cache
 
 DEFAULT_PROFILES = ("mixed", "edge_heavy")
 DEFAULT_NODES = (16, 64)
@@ -192,6 +193,7 @@ def run(profiles=DEFAULT_PROFILES, node_counts=DEFAULT_NODES,
 
 
 def main():
+    enable_compile_cache()
     ap = common.sweep_parser("BENCH_autoscale.json", DEFAULT_PROFILES,
                              DEFAULT_NODES, policies=tuple(POLICIES))
     args = ap.parse_args()

@@ -36,6 +36,7 @@ from repro.core.carbon import CarbonPolicy, diurnal_fleet_signal
 from repro.cluster.node import DEFAULT_REGIONS, make_scenario_cluster
 from repro.cluster.simulator import run_scenario
 from repro.cluster.workload import PoissonArrivals
+from repro.device import enable_compile_cache
 
 DEFAULT_PROFILES = ("mixed", "edge_heavy")
 DEFAULT_NODES = (16, 64)
@@ -179,6 +180,7 @@ def run(profiles=DEFAULT_PROFILES, node_counts=DEFAULT_NODES,
 
 
 def main():
+    enable_compile_cache()
     ap = common.sweep_parser("BENCH_carbon.json", DEFAULT_PROFILES,
                              DEFAULT_NODES, schemes=DEFAULT_SCHEMES)
     args = ap.parse_args()

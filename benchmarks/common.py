@@ -121,11 +121,11 @@ def provenance() -> dict:
     }
     try:
         import jax
-        from repro.kernels.ops import _on_tpu
+        from repro.kernels.ops import resolve_interpret
         prov["jax_version"] = jax.__version__
         prov["jax_platform"] = jax.default_backend()
         # the default the pallas wrappers resolve `interpret=None` to
-        prov["pallas_interpret"] = not _on_tpu()
+        prov["pallas_interpret"] = resolve_interpret()
     except Exception as e:               # jax broken/absent: record why
         prov["jax_version"] = None
         prov["jax_error"] = repr(e)

@@ -51,7 +51,8 @@ from repro.core.scheduler import (BACKENDS, BatchScheduler, _greedy_assign,
                                   decision_matrix_batch)
 from repro.cluster.node import FleetState, NodeTable, make_fleet_nodes
 from repro.cluster.workload import WORKLOADS, Pod
-from repro.kernels.ops import _on_tpu
+from repro.kernels.ops import resolve_interpret
+from repro.device import enable_compile_cache
 
 DEFAULT_NODES = (64, 1024)
 DEFAULT_SCHEME_COUNTS = (5, 64, 512, 4096)
@@ -90,7 +91,7 @@ def run(backends=common.DEFAULT_BACKENDS, node_counts=DEFAULT_NODES,
         reps: int = 5, out: str | None = "BENCH_pareto.json", seed: int = 0,
         numpy_max_schemes: int = MAX_NUMPY_SCHEMES,
         pallas_max_schemes: int = MAX_PALLAS_SCHEMES) -> dict:
-    interpret_mode = not _on_tpu()
+    interpret_mode = resolve_interpret()
     pods = make_queue(n_pods)
     benefit = benefit_mask()
     results = []
@@ -176,6 +177,7 @@ def run(backends=common.DEFAULT_BACKENDS, node_counts=DEFAULT_NODES,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="all",
                     help="all (= numpy,jax; pallas is opt-in, interpret "

@@ -206,6 +206,8 @@ def check(files=BENCH_FILES, baseline_dir: str = BASELINE_DIR,
 
 
 def main() -> None:
+    from repro.device import enable_compile_cache
+    enable_compile_cache()
     t0 = time.time()
     print("=" * 72)
     print("Table VI — energy by profile x competition (paper headline)")

@@ -28,6 +28,7 @@ except ImportError:          # run as a script: benchmarks/ is sys.path[0]
 from repro.cluster.node import SCENARIO_PROFILES, make_scenario_cluster
 from repro.cluster.simulator import run_scenario
 from repro.cluster.workload import PoissonArrivals
+from repro.device import enable_compile_cache
 
 DEFAULT_PROFILES = tuple(SCENARIO_PROFILES)
 DEFAULT_NODES = (16, 256)
@@ -90,6 +91,7 @@ def run(profiles=DEFAULT_PROFILES, node_counts=DEFAULT_NODES,
 
 
 def main():
+    enable_compile_cache()
     ap = common.sweep_parser("BENCH_scenarios.json", DEFAULT_PROFILES,
                              DEFAULT_NODES, schemes=DEFAULT_SCHEMES)
     args = ap.parse_args()

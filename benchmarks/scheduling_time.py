@@ -49,7 +49,8 @@ except ImportError:          # run as a script: benchmarks/ is sys.path[0]
 from repro.core.scheduler import BACKENDS, BatchScheduler, GreenPodScheduler
 from repro.cluster.node import FleetState, NodeTable, make_fleet_nodes
 from repro.cluster.workload import WORKLOADS, Pod
-from repro.kernels.ops import _on_tpu
+from repro.kernels.ops import resolve_interpret
+from repro.device import enable_compile_cache
 
 DEFAULT_NODES = (4, 256, 2048, 8192, 32768, 65536)
 MAX_PER_POD_NODES = 8192     # the per-pod baseline stops scaling here
@@ -98,7 +99,7 @@ def verify_scores(label: str, got, want, atol=1e-5) -> float:
 def run(backends=BACKENDS, node_counts=DEFAULT_NODES, n_pods: int = 64,
         reps: int = 10, out: str | None = "BENCH_scheduling.json",
         seed: int = 0, pallas_max_nodes: int = MAX_PER_POD_NODES) -> dict:
-    interpret_mode = not _on_tpu()
+    interpret_mode = resolve_interpret()
     pods = make_queue(n_pods)
     results = []
     print("mode,backend,n_nodes,pods,ms_total,us_per_pod")
@@ -177,6 +178,7 @@ def run(backends=BACKENDS, node_counts=DEFAULT_NODES, n_pods: int = 64,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="all",
                     help="all | " + " | ".join(BACKENDS))

@@ -314,6 +314,26 @@ def _pow2_pad_len(n: int) -> int:
     return 1 << max(n - 1, 1).bit_length()
 
 
+def _pad_pod_axis(rows: np.ndarray, valid: np.ndarray,
+                  ws: np.ndarray | None = None):
+    """Pad one round's device scoring inputs along the pod axis to the next
+    power of two: jit and Mosaic both cache compiled programs by shape, so
+    shrinking retry queues (P, P-1, ...) reuse a compiled program instead
+    of compiling one per queue length. ``rows`` — the (P, N, C) criteria
+    tensor or the (P,) kind index — pads with zeros, ``ws`` with ones, and
+    ``valid`` with all-False rows: padding pods are infeasible everywhere,
+    score -inf, and the caller slices them off."""
+    pad = _pow2_pad_len(len(valid)) - len(valid)
+    if pad:
+        rows = np.concatenate(
+            [rows, np.zeros((pad,) + rows.shape[1:], rows.dtype)])
+        valid = np.concatenate(
+            [valid, np.zeros((pad, valid.shape[-1]), bool)])
+        if ws is not None:
+            ws = np.concatenate([ws, np.ones((pad, ws.shape[-1]))])
+    return rows, valid, ws
+
+
 class GreenPodScheduler:
     """TOPSIS-based multi-criteria scheduler (paper §III).
 
@@ -491,21 +511,10 @@ class BatchScheduler:
         ws = np.broadcast_to(w, (len(pods), w.shape[0]))
         if self.backend == "numpy":
             return topsis.batched_closeness_np(mats, ws, self._benefit, valid)
+        p = len(pods)
+        mats, valid, ws = _pad_pod_axis(mats, valid, ws)
         if self.backend == "jax":
             import jax.numpy as jnp
-            # jit caches by shape: pad the pod axis to the next power of two
-            # so shrinking retry bursts (P, P-1, ...) hit the cache instead
-            # of recompiling per queue length. Padding rows are all-invalid,
-            # so they score -inf and are sliced off.
-            p = len(pods)
-            p_pad = 1 << max(p - 1, 1).bit_length()
-            if p_pad != p:
-                pad = p_pad - p
-                mats = np.concatenate(
-                    [mats, np.zeros((pad,) + mats.shape[1:])])
-                ws = np.concatenate([ws, np.ones((pad, ws.shape[-1]))])
-                valid = np.concatenate(
-                    [valid, np.zeros((pad, valid.shape[-1]), bool)])
             cc = topsis.batched_closeness_cc(
                 jnp.asarray(mats), jnp.asarray(ws),
                 jnp.asarray(self._benefit), jnp.asarray(valid))
@@ -513,7 +522,7 @@ class BatchScheduler:
         if self.backend == "pallas":
             from repro.kernels import ops
             return np.asarray(ops.topsis_closeness_batched(
-                mats, ws, self._benefit, valid=valid))
+                mats, ws, self._benefit, valid=valid)[:p])
         raise ValueError(f"unknown backend {self.backend!r}; "
                          f"choose from {BACKENDS}")
 
@@ -539,22 +548,13 @@ class BatchScheduler:
                                                self._benefit,
                                                valid[i]).closeness)
                 for i, k in enumerate(kind_idx)])
+        # padding pods gather kind 0 but are all-invalid (see _pad_pod_axis)
+        p = len(pods)
+        kind_idx, valid, ws = _pad_pod_axis(kind_idx, valid, ws)
         if self.backend == "jax":
             import jax.numpy as jnp
             _jit_helpers()
             self._sync_device(cache, dirty, carbon_moved, grew)
-            # same pod-axis pow2 padding as the rebuild path (jit caches by
-            # shape; shrinking retry bursts reuse the trace). Padding rows
-            # gather kind 0 but are all-invalid -> -inf, sliced off.
-            p = len(pods)
-            p_pad = _pow2_pad_len(p)
-            if p_pad != p:
-                pad = p_pad - p
-                kind_idx = np.concatenate(
-                    [kind_idx, np.zeros(pad, dtype=kind_idx.dtype)])
-                ws = np.concatenate([ws, np.ones((pad, ws.shape[-1]))])
-                valid = np.concatenate(
-                    [valid, np.zeros((pad, valid.shape[-1]), bool)])
             cc = _closeness_from_kinds(
                 self._dev, jnp.asarray(kind_idx), jnp.asarray(ws),
                 jnp.asarray(self._benefit), jnp.asarray(valid))
@@ -563,7 +563,7 @@ class BatchScheduler:
         if self.backend == "pallas":
             from repro.kernels import ops
             return np.asarray(ops.topsis_closeness_kinds(
-                cache.mats, kind_idx, ws, self._benefit, valid=valid))
+                cache.mats, kind_idx, ws, self._benefit, valid=valid)[:p])
         raise ValueError(f"unknown backend {self.backend!r}; "
                          f"choose from {BACKENDS}")
 
@@ -692,13 +692,7 @@ class BatchScheduler:
             _jit_helpers()
             self._sync_device(cache, dirty, carbon_moved, grew)
             p = len(pods)
-            p_pad = _pow2_pad_len(p)
-            if p_pad != p:
-                pad = p_pad - p
-                kind_idx = np.concatenate(
-                    [kind_idx, np.zeros(pad, dtype=kind_idx.dtype)])
-                valid = np.concatenate(
-                    [valid, np.zeros((pad, valid.shape[-1]), bool)])
+            kind_idx, valid, _ = _pad_pod_axis(kind_idx, valid)
             cc = _closeness_grid_from_kinds(
                 self._dev, jnp.asarray(kind_idx), jnp.asarray(ws),
                 jnp.asarray(self._benefit), jnp.asarray(valid))

@@ -1,5 +1,6 @@
-"""Public jit'd wrappers around the Pallas kernels: padding, layout, backend
-dispatch (interpret mode off-TPU), and shape restoration.
+"""Public wrappers around the Pallas kernels: padding, layout, backend
+dispatch (compiled on TPU, interpret mode on CPU, an error elsewhere — see
+:func:`resolve_interpret`), and shape restoration.
 
 These are the entry points the rest of the framework uses; each has a
 pure-jnp oracle in repro.kernels.ref and a sweep test in tests/test_kernels.py.
@@ -20,8 +21,22 @@ from repro.kernels import topsis_pallas as _tp
 _EPS = 1e-12
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Whether a Pallas call runs in the interpreter: an explicit
+    ``interpret`` wins; otherwise the default backend decides — ``cpu``
+    interprets (the tests and CPU rehearsals), ``tpu`` compiles the kernels
+    with Mosaic, and any other platform raises rather than fall back to
+    the interpreter unannounced."""
+    if interpret is not None:
+        return interpret
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default JAX backend is {platform!r}")
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -55,8 +70,7 @@ def topsis_closeness(matrix: jax.Array, weights: jax.Array,
     points and returned as -inf (never rank first) — identical semantics to
     ``repro.core.topsis.closeness``.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     n, c = matrix.shape
     assert c <= _tp.C_PAD, f"at most {_tp.C_PAD} criteria, got {c}"
     benefit = jnp.asarray(benefit, bool)
@@ -100,8 +114,7 @@ def topsis_closeness_batched(mats: jax.Array, weights: jax.Array,
     optional (P, N) feasibility mask (excluded from ideals, -inf in the
     result, as in the single-matrix form).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     mats = jnp.asarray(mats).astype(jnp.float32)
     p, n, c = mats.shape
     assert c <= _tp.C_PAD, f"at most {_tp.C_PAD} criteria, got {c}"
@@ -148,8 +161,7 @@ def topsis_closeness_grid(mats: jax.Array, weights: jax.Array,
     is the usual (P, N) feasibility mask, shared by every scheme; row
     semantics match ``repro.core.topsis.closeness_grid``.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     mats = jnp.asarray(mats).astype(jnp.float32)
     p, n, c = mats.shape
     assert c <= _tp.C_PAD, f"at most {_tp.C_PAD} criteria, got {c}"
@@ -205,8 +217,7 @@ def topsis_closeness_kinds(mats_kinds: jax.Array, kind_idx: jax.Array,
     XLA; ``weights`` is (C,) shared or (P, C) per pod; result semantics
     (invalid -> -inf) match :func:`topsis_closeness_batched`.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     mats_kinds = jnp.asarray(mats_kinds).astype(jnp.float32)
     k, n, c = mats_kinds.shape
     kind_idx = jnp.asarray(kind_idx, jnp.int32)
@@ -245,8 +256,7 @@ def topsis_closeness_kinds(mats_kinds: jax.Array, kind_idx: jax.Array,
 def rmsnorm(x: jax.Array, gamma: jax.Array, eps: float = 1e-6, *,
             block_rows: int = 256, interpret: bool | None = None) -> jax.Array:
     """Fused RMSNorm over the last axis of x (any leading shape)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     d = x.shape[-1]
     lead = x.shape[:-1]
     rows = int(np.prod(lead)) if lead else 1
@@ -295,8 +305,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """(B, H, S, D) GQA flash attention; pads S to block multiples and D to
     the 128-lane boundary. Differentiable: backward runs the flash backward
     Pallas kernels (dq + fused dk/dv), not a rematerialized-score fallback."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     b, h, sq, d = q.shape
     skv = k.shape[2]
     if sm_scale is None:

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import registry
 from repro.launch import hlo_analysis, specs
 from repro.launch.mesh import make_production_mesh
@@ -177,7 +176,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, hlo_dir: str | None = None,
                  "chips": chips, "opt": opt, "plan": dataclasses.asdict(plan)}
     try:
         t0 = time.time()
-        compat.set_mesh(mesh)   # ambient mesh for shard_map'd Pallas kernels
+        jax.set_mesh(mesh)   # ambient mesh for shard_map'd Pallas kernels
         with mesh:
             jitted, args, cfg, c = build_cell(arch, shape, mesh, plan)
             lowered = jitted.lower(*args)

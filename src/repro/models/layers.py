@@ -13,7 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models.config import ModelConfig
 
 Params = dict[str, Any]
@@ -81,6 +80,12 @@ def attn_init(key, cfg: ModelConfig, d_kv_in: int | None = None) -> Params:
     }
 
 
+def _ambient_mesh():
+    """The ambient mesh (``jax.set_mesh``), or None when none is set."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
+
+
 # materializing (S, T) logits beyond this many query rows switches to the
 # exact q-chunked path (bounds live memory to (B, H, CHUNK, T)).
 _Q_CHUNK = 4096
@@ -89,7 +94,7 @@ _Q_CHUNK = 4096
 def _flash_shardable(cfg: ModelConfig) -> bool:
     """Flash path needs an ambient mesh whose model axis divides the query
     heads (each rank runs the kernel on its local heads)."""
-    mesh = compat.get_abstract_mesh()
+    mesh = _ambient_mesh()
     if mesh is None or "model" not in mesh.axis_names:
         return False
     m = mesh.shape["model"]
@@ -115,7 +120,7 @@ def _flash_sdpa(cfg: ModelConfig, q, k, v, *, causal: bool,
     traffic (launch/hlo_analysis.py VMEM-scope rule)."""
     from repro.kernels import ops as kops   # local import: no cycle at load
 
-    mesh = compat.get_abstract_mesh()
+    mesh = _ambient_mesh()
     m = mesh.shape["model"]
     ba = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     b, s, h, d = q.shape
@@ -138,10 +143,11 @@ def _flash_sdpa(cfg: ModelConfig, q, k, v, *, causal: bool,
                                     window=window)
 
     kv_spec = P(ba, "model" if kv_sharded else None, None, None)
-    out = compat.shard_map(local, mesh=mesh,
-                           in_specs=(P(ba, "model", None, None),
-                                     kv_spec, kv_spec),
-                           out_specs=P(ba, "model", None, None))(
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(P(ba, "model", None, None),
+                                  kv_spec, kv_spec),
+                        out_specs=P(ba, "model", None, None),
+                        check_vma=False)(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3))
     return out.transpose(0, 2, 1, 3)
@@ -174,7 +180,7 @@ def _sdpa(q, k, v, *, causal, window, q_pos=None, kv_len=None):
     # For TRAIN/PREFILL with hkv not divisible by the model axis, grouped
     # logits (B,hkv,g,S,T) lose their clean head sharding and cost MORE
     # (llama-3.2-vision-90b train: memory +11%) — use repeat there.
-    mesh = compat.get_abstract_mesh()
+    mesh = _ambient_mesh()
     m = mesh.shape.get("model", 1) if mesh is not None \
         and hasattr(mesh, "shape") else 1
     grouped = (s == 1) or hkv % max(m, 1) == 0 or hkv == h

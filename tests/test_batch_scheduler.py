@@ -150,6 +150,34 @@ def test_batch_scheduler_scores_match_numpy(backend):
     np.testing.assert_allclose(got[finite], want[finite], atol=1e-5)
 
 
+@pytest.mark.parametrize("attached", [False, True],
+                         ids=["rebuild", "incremental"])
+def test_pallas_shrinking_queues_share_one_kernel(attached):
+    """The Pallas rounds pad the pod axis to a power of two, as the jax
+    rounds do: queues of 8, 7, 6 and 5 pods reuse one compiled kernel, and
+    every round still matches the numpy reference."""
+    from repro.cluster.node import FleetState, make_fleet_nodes
+    from repro.kernels import topsis_pallas as tp
+    kernel = (tp.topsis_closeness_kinds_blocks if attached
+              else tp.topsis_closeness_batched_blocks)
+    fleet = FleetState.from_nodes(make_fleet_nodes(100, seed=3,
+                                                   utilization=0.4))
+    sched = BatchScheduler("energy_centric", backend="pallas")
+    if attached:
+        sched.attach(fleet)
+    ref = BatchScheduler("energy_centric", backend="numpy")
+    pods = make_queue(8, seed=9)
+    before = kernel._cache_size()
+    for p in (8, 7, 6, 5):
+        got = sched.score_queue(pods[:p], fleet)
+        want = ref.score_queue(pods[:p], NodeTable.from_nodes(fleet.nodes))
+        assert got.shape == want.shape == (p, 100)
+        finite = np.isfinite(want)
+        np.testing.assert_array_equal(finite, np.isfinite(got))
+        np.testing.assert_allclose(got[finite], want[finite], atol=1e-5)
+    assert kernel._cache_size() - before <= 1
+
+
 @pytest.mark.parametrize("backend", ["jax"])
 def test_batch_scheduler_assignments_match_numpy(backend):
     pods = make_queue(16, seed=11)

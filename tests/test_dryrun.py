@@ -41,9 +41,8 @@ def test_tiny_mesh_train_lower_compile_smoke_arch():
         from repro.sharding import rules
         from repro.train import loop as tl
         from repro.launch import hlo_analysis
-        from repro import compat
         mesh = jax.make_mesh((4, 2), ("data", "model"),
-                             **compat.axis_types_kwarg(2))
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = registry.smoke_config("llama3-8b")
         model = lm.build(cfg)
         ocfg = adamw.AdamWConfig(lr=1e-3, warmup_steps=0)
@@ -96,11 +95,10 @@ def test_tiny_mesh_decode_and_elastic_restore():
         from repro.models import lm
         from repro.sharding import rules
         from repro.train import checkpoint as ckpt
-        from repro import compat
         mesh1 = jax.make_mesh((4, 2), ("data", "model"),
-                              **compat.axis_types_kwarg(2))
+                              axis_types=(jax.sharding.AxisType.Auto,) * 2)
         mesh2 = jax.make_mesh((2, 4), ("data", "model"),
-                              **compat.axis_types_kwarg(2))
+                              axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = registry.smoke_config("llama3-8b")
         model = lm.build(cfg)
         params = model.init(jax.random.PRNGKey(0))
@@ -141,9 +139,8 @@ def test_moe_expert_parallel_tiny_mesh():
         from repro.models import lm
         from repro.sharding import rules
         import dataclasses
-        from repro import compat
         mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             **compat.axis_types_kwarg(2))
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = dataclasses.replace(registry.smoke_config("mixtral-8x7b"),
                                   moe_groups=2)
         model = lm.build(cfg)

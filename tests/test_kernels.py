@@ -31,6 +31,24 @@ def test_topsis_kernel_sweep(n, c):
                                atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("platform,want", [("cpu", True), ("tpu", False),
+                                           ("gpu", None)])
+def test_interpret_mode_follows_platform(monkeypatch, platform, want):
+    """cpu interprets, tpu compiles, any other platform raises naming
+    itself — no silent interpreter fallback; an explicit flag wins."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert ops.resolve_interpret(False) is False
+    assert ops.resolve_interpret(True) is True
+    if want is None:
+        with pytest.raises(RuntimeError, match=repr(platform)):
+            ops.resolve_interpret()
+        with pytest.raises(RuntimeError, match=repr(platform)):
+            ops.topsis_closeness(jnp.ones((4, 5)), jnp.ones(5),
+                                 jnp.ones(5, bool))
+    else:
+        assert ops.resolve_interpret() is want
+
+
 @pytest.mark.parametrize("block_n", [128, 256, 2048])
 def test_topsis_kernel_block_shapes(block_n):
     key = jax.random.PRNGKey(0)
