@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+from collections import Counter
 from typing import Callable, Sequence
 
 import numpy as np
@@ -379,7 +380,6 @@ class EventEngine:
                        RunningTask(start + rt, pod.uid, pod, idx,
                                    len(st.records) - 1,
                                    len(st.timeline.segments) - 1))
-        telemetry.active().inc("engine_commits", scheduler=pod.scheduler)
 
     def _pop_release(self) -> float:
         """Pop the earliest completion, release its resources, notify the
@@ -390,7 +390,6 @@ class EventEngine:
         for pol in self.policies:
             pol.on_completion(self, done.node_index, done.end_s)
         st.event_log.append((done.end_s, COMPLETION, done.uid))
-        telemetry.active().inc("engine_events", kind=COMPLETION)
         return done.end_s
 
     def _run_burst(self, pods: list[Pod], t: float,
@@ -412,12 +411,131 @@ class EventEngine:
         assignments, diag = st.schedulers[scheduler].select_many(
             pods, st.fleet, now=t, blocked=blocked, exclude=exclude)
         still: list[Pod] = []
-        for pod, idx in zip(pods, assignments):
-            if idx is None:
-                still.append(pod)
-                continue
-            self._commit(pod, idx, t, diag["per_pod_time_s"])
+        tel = telemetry.active()
+        with tel.stage("engine_commit"):
+            for pod, idx in zip(pods, assignments):
+                if idx is None:
+                    still.append(pod)
+                    continue
+                self._commit(pod, idx, t, diag["per_pod_time_s"])
+        if len(still) < len(pods):
+            tel.inc("engine_commits", value=float(len(pods) - len(still)),
+                    scheduler=scheduler)
         return still
+
+    def _hooks(self, tel, hook: str):
+        """The ``engine_policies`` span around one policy-hook phase of a
+        round, entered only when the run has policies."""
+        if not self.policies:
+            tel = telemetry.NULL
+        return tel.stage("engine_policies", hook=hook)
+
+    def _round(self, t: float, tel) -> list[Pod]:
+        """One scheduling round at clock ``t``: the policies' round-start
+        mutations, exclusion masks and deferral holds, then every pending
+        pod placed that fits (FIFO retry for the rest). Returns the pods
+        held out of the round (deferred, or blocked from an instant
+        same-node restart)."""
+        st = self.state
+        policies = self.policies
+        with self._hooks(tel, "clock"):
+            for pol in policies:
+                pol.on_clock(self, t)
+        # round-start mutations: carbon preemption evictions, the
+        # consolidation drain pass — requeued pods re-enter this round's
+        # pending queue
+        with self._hooks(tel, "round_start"):
+            for pol in policies:
+                pol.on_round_start(self, t)
+        blocked_now = {uid: b.node_index
+                       for uid, b in st.blocked.items() if b.t == t}
+        # exclusion masks for this round: the OR of every policy's
+        # fleet-wide mask, plus per-pod extras (a policy may forbid
+        # specific nodes for specific pods — deadline-late WAKING nodes
+        # for deferrable pods)
+        base_ex = None
+        with self._hooks(tel, "exclude_mask"):
+            for pol in policies:
+                m = pol.exclude_mask(self, t)
+                if m is not None:
+                    base_ex = m if base_ex is None else (base_ex | m)
+
+        def _exclude_for(pod: Pod):
+            # per-pod extras run even when no policy set a fleet-wide
+            # mask (base may be None — a policy can be purely per-pod)
+            mask = base_ex
+            for pol in policies:
+                extra = pol.exclude_for(self, pod, mask, t)
+                if extra is not None:
+                    mask = extra
+            return mask
+        # deferral filter: policies hold pods out of this round (they keep
+        # their queue position and retry at the policy's wake)
+        held: list[Pod] = []
+        held_uids: set[int] = set()
+        with self._hooks(tel, "filter_pending"):
+            for pol in policies:
+                n_held = 0
+                for p in pol.filter_pending(self, st.pending, t):
+                    if p.uid not in held_uids:
+                        held.append(p)
+                        held_uids.add(p.uid)
+                        n_held += 1
+                if n_held:
+                    tel.inc("policy_deferred_pods", value=float(n_held),
+                            policy=type(pol).__name__)
+        # scheduling round: place what fits, FIFO retry for the rest.
+        # Batch-capable schedulers take the burst path, grouped by
+        # pod.scheduler (in first-appearance order) so a mixed queue
+        # routes each group through its own scoring engine
+        placed: set[int] = set()
+        bursts: dict[str, list[Pod]] = {}
+        for pod in st.pending:
+            if pod.uid in held_uids:
+                continue
+            sched = st.schedulers[pod.scheduler]
+            if self.batch and hasattr(sched, "select_many"):
+                bursts.setdefault(pod.scheduler, []).append(pod)
+                continue
+            idx, diag = sched.select(
+                pod, st.fleet, now=t, exclude=_exclude_for(pod))
+            if idx is None:
+                continue
+            if blocked_now.get(pod.uid) == idx:
+                # blocked instant same-node restart: wait like a deferred
+                # pod (guarantees a wake event to retry on)
+                held.append(pod)
+                held_uids.add(pod.uid)
+                continue
+            self._commit(pod, idx, t, diag["scheduling_time_s"])
+            tel.inc("engine_commits", scheduler=pod.scheduler)
+            placed.add(pod.uid)
+        for group, burst in bursts.items():
+            with self._hooks(tel, "exclude_for"):
+                per_pod = [_exclude_for(p) for p in burst]
+            if any(pp is not base_ex for pp in per_pod):
+                # a policy set per-pod extras: stack to (P, N), padding
+                # unmasked pods with the base (or an empty mask)
+                fill = (base_ex if base_ex is not None
+                        else np.zeros(len(st.nodes), dtype=bool))
+                ex_b = np.stack([pp if pp is not None else fill
+                                 for pp in per_pod])
+            else:
+                ex_b = base_ex
+            b_still = self._run_burst(burst, t, blocked_now, ex_b,
+                                      scheduler=group)
+            placed.update({p.uid for p in burst} - {p.uid for p in b_still})
+        st.pending = [p for p in st.pending if p.uid not in placed]
+        # evicted-but-unplaced victims wait like held pods (the block
+        # lapses once t advances)
+        for p in st.pending:
+            if p.uid in blocked_now and p.uid not in held_uids:
+                held.append(p)
+                held_uids.add(p.uid)
+        with self._hooks(tel, "round_end"):
+            for pol in policies:
+                pol.on_round_end(self, st.pending, held, t)
+        return held
 
     def _record_series(self, tel) -> None:
         """Sample the sim-time metric timelines at the current clock
@@ -498,7 +616,6 @@ class EventEngine:
                     st.arrival_s.setdefault(p.uid, burst_t)
                 st.pending.extend(burst_pods)
                 st.event_log.append((burst_t, ARRIVAL, len(burst_pods)))
-                tel.inc("engine_events", kind=ARRIVAL)
                 ei += 1
             # safety net: release anything that finished before now (the
             # advance step never moves the clock past an unreleased
@@ -508,107 +625,20 @@ class EventEngine:
             if not st.pending and not st.running and ei >= len(events):
                 break
             t = st.t
-            # queue-depth gauges, sampled once per clock instant's round
-            tel.set_gauge("engine_pending_depth", float(len(st.pending)))
-            tel.set_gauge("engine_running_tasks", float(len(st.running)))
-            for pol in policies:
-                pol.on_clock(self, t)
-            with tel.span("engine_round"):
-                # round-start mutations: carbon preemption evictions, the
-                # consolidation drain pass — requeued pods re-enter this
-                # round's pending queue
-                for pol in policies:
-                    pol.on_round_start(self, t)
-                blocked_now = {uid: b.node_index
-                               for uid, b in st.blocked.items() if b.t == t}
-                # exclusion masks for this round: the OR of every policy's
-                # fleet-wide mask, plus per-pod extras (a policy may forbid
-                # specific nodes for specific pods — deadline-late WAKING
-                # nodes for deferrable pods)
-                base_ex = None
-                for pol in policies:
-                    m = pol.exclude_mask(self, t)
-                    if m is not None:
-                        base_ex = m if base_ex is None else (base_ex | m)
-
-                def _exclude_for(pod: Pod):
-                    # per-pod extras run even when no policy set a
-                    # fleet-wide mask (base may be None — a policy can be
-                    # purely per-pod)
-                    mask = base_ex
-                    for pol in policies:
-                        extra = pol.exclude_for(self, pod, mask, t)
-                        if extra is not None:
-                            mask = extra
-                    return mask
-                # deferral filter: policies hold pods out of this round
-                # (they keep their queue position and retry at the
-                # policy's wake)
-                held: list[Pod] = []
-                held_uids: set[int] = set()
-                for pol in policies:
-                    n_held = 0
-                    for p in pol.filter_pending(self, st.pending, t):
-                        if p.uid not in held_uids:
-                            held.append(p)
-                            held_uids.add(p.uid)
-                            n_held += 1
-                    if n_held:
-                        tel.inc("policy_deferred_pods", value=float(n_held),
-                                policy=type(pol).__name__)
-                # scheduling round: place what fits, FIFO retry for the
-                # rest. Batch-capable schedulers take the burst path,
-                # grouped by pod.scheduler (in first-appearance order) so
-                # a mixed queue routes each group through its own scoring
-                # engine
-                placed: set[int] = set()
-                bursts: dict[str, list[Pod]] = {}
-                for pod in st.pending:
-                    if pod.uid in held_uids:
-                        continue
-                    sched = st.schedulers[pod.scheduler]
-                    if self.batch and hasattr(sched, "select_many"):
-                        bursts.setdefault(pod.scheduler, []).append(pod)
-                        continue
-                    idx, diag = sched.select(
-                        pod, st.fleet, now=t, exclude=_exclude_for(pod))
-                    if idx is None:
-                        continue
-                    if blocked_now.get(pod.uid) == idx:
-                        # blocked instant same-node restart: wait like a
-                        # deferred pod (guarantees a wake event to retry
-                        # on)
-                        held.append(pod)
-                        held_uids.add(pod.uid)
-                        continue
-                    self._commit(pod, idx, t, diag["scheduling_time_s"])
-                    placed.add(pod.uid)
-                for group, burst in bursts.items():
-                    per_pod = [_exclude_for(p) for p in burst]
-                    if any(pp is not base_ex for pp in per_pod):
-                        # a policy set per-pod extras: stack to (P, N),
-                        # padding unmasked pods with the base (or an empty
-                        # mask)
-                        fill = (base_ex if base_ex is not None
-                                else np.zeros(len(st.nodes), dtype=bool))
-                        ex_b = np.stack([pp if pp is not None else fill
-                                         for pp in per_pod])
-                    else:
-                        ex_b = base_ex
-                    b_still = self._run_burst(burst, t, blocked_now, ex_b,
-                                              scheduler=group)
-                    placed.update({p.uid for p in burst}
-                                  - {p.uid for p in b_still})
-                st.pending = [p for p in st.pending if p.uid not in placed]
-                # evicted-but-unplaced victims wait like held pods (the
-                # block lapses once t advances)
-                for p in st.pending:
-                    if p.uid in blocked_now and p.uid not in held_uids:
-                        held.append(p)
-                        held_uids.add(p.uid)
-                for pol in policies:
-                    pol.on_round_end(self, st.pending, held, t)
-            if tel.enabled:
+            if tel.timelines:
+                # queue-depth gauges, sampled at every clock advance like
+                # the timelines
+                tel.set_gauge("engine_pending_depth", float(len(st.pending)))
+                tel.set_gauge("engine_running_tasks",
+                              float(len(st.running)))
+            held: list[Pod] = []
+            if policies or st.pending:
+                # a round only where a queue is scored or a policy hook
+                # runs: a policy-free completion with nothing pending
+                # would find nothing to do
+                with tel.stage("engine_round"):
+                    held = self._round(t, tel)
+            if tel.timelines:
                 self._record_series(tel)
             # advance the clock to the earliest candidate event:
             # completion, arrival burst, or a policy wake
@@ -647,7 +677,6 @@ class EventEngine:
                 st.t = next_wake
                 st.event_log.append((wake_ev.t, wake_ev.kind,
                                      wake_ev.payload))
-                tel.inc("engine_events", kind=wake_ev.kind)
                 wake_pol.on_tick(self, wake_ev)
                 continue
             if st.pending:
@@ -669,9 +698,13 @@ class EventEngine:
             pol.finalize(self, horizon)
         if tel.enabled:
             # end-of-run rollups (observer-only; guarded so disabled runs
-            # skip the ledger walk entirely)
-            st.timeline.publish_telemetry(tel)
-            st.timeline.publish_series(tel)
+            # skip the ledger walk entirely). Events are counted off the
+            # event log here, not one call per completion in the loop.
+            for kind, n in Counter(ev[1] for ev in st.event_log).items():
+                tel.inc("engine_events", value=float(n), kind=kind)
+            if tel.timelines:
+                st.timeline.publish_telemetry(tel)
+                st.timeline.publish_series(tel)
             tel.set_gauge("engine_unschedulable", float(st.unschedulable))
         explanations: list | None = None
         for sched in st.schedulers.values():

@@ -335,7 +335,7 @@ class CarbonScheduling(SchedulingPolicy):
 
     def on_clock(self, sim, t: float) -> None:
         tel = telemetry.active()
-        if tel.enabled:
+        if tel.timelines:
             # observer-only: the grid-intensity timeline each region saw,
             # sampled at the clock instants the engine actually visited
             for region in self.fleet_regions:

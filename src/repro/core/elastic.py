@@ -529,7 +529,7 @@ class AutoscaleScheduling(SchedulingPolicy):
     def on_clock(self, sim, t: float) -> None:
         self.fleet.advance_to(t)
         tel = telemetry.active()
-        if tel.enabled:
+        if tel.timelines:
             # observer-only: per-state node counts over sim time (states()
             # is a read-only view, so recording can't perturb the run)
             states = self.fleet.states(t)

@@ -119,26 +119,45 @@ def _greedy_assign(cc: np.ndarray, pods: Sequence[Pod], table: NodeTable,
     Extracted from :meth:`BatchScheduler.select_many` so the grid path
     commits every scheme through identical code — the per-scheme ledgers
     are independent what-if placements off the same snapshot."""
-    order = np.argsort(-cc, kind="stable", axis=-1)
-    free_cpu = table.free_cpu.copy()
-    free_mem = table.free_mem.copy()
-    assignments: list[int | None] = []
-    for i, pod in enumerate(pods):
-        forbid = blocked[i] if blocked is not None else None
-        chosen = None
-        for j in order[i]:
-            if np.isneginf(cc[i, j]):
-                break           # rest of the ranking is infeasible
-            if forbid is not None and int(j) == forbid:
-                continue
-            if free_cpu[j] >= pod.cpu - 1e-9 \
-                    and free_mem[j] >= pod.mem - 1e-9:
-                chosen = int(j)
-                free_cpu[j] -= pod.cpu
-                free_mem[j] -= pod.mem
-                break
-        assignments.append(chosen)
+    tel = telemetry.active()
+    with tel.stage("scheduler_argsort"):
+        order = np.argsort(-cc, kind="stable", axis=-1)
+    with tel.stage("scheduler_walk"):
+        free_cpu = table.free_cpu.copy()
+        free_mem = table.free_mem.copy()
+        assignments: list[int | None] = []
+        for i, pod in enumerate(pods):
+            forbid = blocked[i] if blocked is not None else None
+            chosen = None
+            for j in order[i]:
+                if np.isneginf(cc[i, j]):
+                    break           # rest of the ranking is infeasible
+                if forbid is not None and int(j) == forbid:
+                    continue
+                if free_cpu[j] >= pod.cpu - 1e-9 \
+                        and free_mem[j] >= pod.mem - 1e-9:
+                    chosen = int(j)
+                    free_cpu[j] -= pod.cpu
+                    free_mem[j] -= pod.mem
+                    break
+            assignments.append(chosen)
     return assignments
+
+
+def _upload(tel, arrays):
+    """Put one round's host inputs on the device, counting the bytes."""
+    import jax
+    out = jax.device_put(arrays)
+    tel.inc("scheduler_upload_bytes", value=float(sum(a.nbytes for a in out)))
+    return out
+
+
+def _readback(tel, cc, labels: dict) -> np.ndarray:
+    """Wait for the device's scores and copy them to the host."""
+    with tel.stage("scheduler_readback", **labels):
+        out = np.asarray(cc)
+    tel.inc("scheduler_readback_bytes", value=float(out.nbytes))
+    return out
 
 
 def _check_carbon_scheme(scheme: str, carbon_signal) -> None:
@@ -248,7 +267,6 @@ class FleetCriteriaCache:
                 grew = True
                 new_kinds += 1
             kind_idx[i] = k
-        tel.inc("cache_syncs")
         if dirty.size:
             tel.inc("cache_dirty_columns", value=float(dirty.size))
         if carbon_moved:
@@ -495,34 +513,39 @@ class BatchScheduler:
         path below, which is kept verbatim as the reference oracle
         (tests/test_fleet_state.py asserts the two agree bitwise)."""
         table = _as_table(nodes)
+        tel = telemetry.active()
+        tel.inc("scheduler_pods_scored", value=float(len(pods)))
         if self._cache is not None and table is self._cache.fleet:
-            telemetry.active().inc("scheduler_score_queue",
-                                   path="incremental")
             return self._score_queue_incremental(pods, table, now, exclude)
-        telemetry.active().inc("scheduler_score_queue", path="rebuild")
-        inten = (self.carbon_signal.intensities(table.region, now)
-                 if self.carbon_signal is not None else None)
-        mats = decision_matrix_batch(pods, table, carbon_intensity=inten)
-        valid = table.fits(np.asarray([p.cpu for p in pods])[:, None],
-                           np.asarray([p.mem for p in pods])[:, None])
-        if exclude is not None:
-            valid = valid & ~np.asarray(exclude, dtype=bool)
-        w = self.weights(table)
-        ws = np.broadcast_to(w, (len(pods), w.shape[0]))
+        labels = {"backend": self.backend, "path": "rebuild"}
+        with tel.stage("scheduler_sync", **labels):
+            inten = (self.carbon_signal.intensities(table.region, now)
+                     if self.carbon_signal is not None else None)
+            mats = decision_matrix_batch(pods, table, carbon_intensity=inten)
+        with tel.stage("scheduler_mask", **labels):
+            valid = table.fits(np.asarray([p.cpu for p in pods])[:, None],
+                               np.asarray([p.mem for p in pods])[:, None])
+            if exclude is not None:
+                valid = valid & ~np.asarray(exclude, dtype=bool)
+            w = self.weights(table)
+            ws = np.broadcast_to(w, (len(pods), w.shape[0]))
+            p = len(pods)
+            if self.backend != "numpy":
+                mats, valid, ws = _pad_pod_axis(mats, valid, ws)
         if self.backend == "numpy":
             return topsis.batched_closeness_np(mats, ws, self._benefit, valid)
-        p = len(pods)
-        mats, valid, ws = _pad_pod_axis(mats, valid, ws)
         if self.backend == "jax":
-            import jax.numpy as jnp
-            cc = topsis.batched_closeness_cc(
-                jnp.asarray(mats), jnp.asarray(ws),
-                jnp.asarray(self._benefit), jnp.asarray(valid))
-            return np.asarray(cc[:p])
+            with tel.stage("scheduler_upload", **labels):
+                args = _upload(tel, (mats, ws, self._benefit, valid))
+            with tel.stage("scheduler_dispatch", **labels):
+                cc = topsis.batched_closeness_cc(*args)[:p]
+            return _readback(tel, cc, labels)
         if self.backend == "pallas":
             from repro.kernels import ops
-            return np.asarray(ops.topsis_closeness_batched(
-                mats, ws, self._benefit, valid=valid)[:p])
+            with tel.stage("scheduler_dispatch", **labels):
+                cc = ops.topsis_closeness_batched(
+                    mats, ws, self._benefit, valid=valid)[:p]
+            return _readback(tel, cc, labels)
         raise ValueError(f"unknown backend {self.backend!r}; "
                          f"choose from {BACKENDS}")
 
@@ -534,36 +557,44 @@ class BatchScheduler:
         as a row gather — numpy reads zero-copy views, jax gathers from
         the device-resident mirror, pallas streams kind blocks through the
         scalar-prefetch kernel."""
+        tel = telemetry.active()
+        labels = {"backend": self.backend, "path": "incremental"}
         cache = self._cache
-        kind_idx, dirty, carbon_moved, grew = cache.sync(pods, now)
-        valid = fleet.fits(np.asarray([p.cpu for p in pods])[:, None],
-                           np.asarray([p.mem for p in pods])[:, None])
-        if exclude is not None:
-            valid = valid & ~np.asarray(exclude, dtype=bool)
-        w = self.weights(fleet)
-        ws = np.broadcast_to(w, (len(pods), w.shape[0]))
+        with tel.stage("scheduler_sync", **labels):
+            kind_idx, dirty, carbon_moved, grew = cache.sync(pods, now)
+        with tel.stage("scheduler_mask", **labels):
+            valid = fleet.fits(np.asarray([p.cpu for p in pods])[:, None],
+                               np.asarray([p.mem for p in pods])[:, None])
+            if exclude is not None:
+                valid = valid & ~np.asarray(exclude, dtype=bool)
+            w = self.weights(fleet)
+            ws = np.broadcast_to(w, (len(pods), w.shape[0]))
+            p = len(pods)
+            if self.backend != "numpy":
+                # padding pods gather kind 0 but are all-invalid (see
+                # _pad_pod_axis)
+                kind_idx, valid, ws = _pad_pod_axis(kind_idx, valid, ws)
         if self.backend == "numpy":
             return np.stack([
                 np.asarray(topsis.closeness_np(cache.mats[k], ws[i],
                                                self._benefit,
                                                valid[i]).closeness)
                 for i, k in enumerate(kind_idx)])
-        # padding pods gather kind 0 but are all-invalid (see _pad_pod_axis)
-        p = len(pods)
-        kind_idx, valid, ws = _pad_pod_axis(kind_idx, valid, ws)
         if self.backend == "jax":
-            import jax.numpy as jnp
             _jit_helpers()
-            self._sync_device(cache, dirty, carbon_moved, grew)
-            cc = _closeness_from_kinds(
-                self._dev, jnp.asarray(kind_idx), jnp.asarray(ws),
-                jnp.asarray(self._benefit), jnp.asarray(valid))
-            telemetry.active().inc("cache_fused_dispatches", backend="jax")
-            return np.asarray(cc[:p])
+            with tel.stage("scheduler_upload", **labels):
+                self._sync_device(cache, dirty, carbon_moved, grew)
+                args = _upload(tel, (kind_idx, ws, self._benefit, valid))
+            with tel.stage("scheduler_dispatch", **labels):
+                cc = _closeness_from_kinds(self._dev, *args)[:p]
+            return _readback(tel, cc, labels)
         if self.backend == "pallas":
             from repro.kernels import ops
-            return np.asarray(ops.topsis_closeness_kinds(
-                cache.mats, kind_idx, ws, self._benefit, valid=valid)[:p])
+            with tel.stage("scheduler_dispatch", **labels):
+                cc = ops.topsis_closeness_kinds(
+                    cache.mats, kind_idx, ws, self._benefit,
+                    valid=valid)[:p]
+            return _readback(tel, cc, labels)
         raise ValueError(f"unknown backend {self.backend!r}; "
                          f"choose from {BACKENDS}")
 
@@ -581,6 +612,7 @@ class BatchScheduler:
             tel.inc("cache_device_reuploads",
                     reason="growth" if self._dev is not None else "first")
             self._dev = jnp.asarray(cache.mats.astype(np.float32))
+            tel.inc("scheduler_upload_bytes", value=float(self._dev.nbytes))
             return
         if dirty.size:
             tel.inc("cache_device_scatters")
@@ -589,12 +621,12 @@ class BatchScheduler:
                 [dirty, np.full(d_pad - dirty.size, dirty[0],
                                 dtype=dirty.dtype)])
             block = cache.mats[:, idx, :].astype(np.float32)
-            self._dev = _scatter_node_cols(self._dev, jnp.asarray(idx),
-                                           jnp.asarray(block))
+            self._dev = _scatter_node_cols(self._dev,
+                                           *_upload(tel, (idx, block)))
         if carbon_moved and self.carbon_signal is not None:
             tel.inc("cache_device_carbon_updates")
             col = cache.mats[:, :, -1].astype(np.float32)
-            self._dev = _set_carbon_col(self._dev, jnp.asarray(col))
+            self._dev = _set_carbon_col(self._dev, *_upload(tel, (col,)))
 
     def _weight_grid(self, schemes) -> np.ndarray:
         """Resolve ``schemes`` — a sequence of scheme names or an (S, C)
@@ -640,28 +672,33 @@ class BatchScheduler:
         tensor, with no re-upload per scheme."""
         table = _as_table(nodes)
         ws = self._weight_grid(schemes)
+        tel = telemetry.active()
+        tel.inc("scheduler_pods_scored", value=float(len(pods)))
         if self._cache is not None and table is self._cache.fleet:
-            telemetry.active().inc("scheduler_score_grid",
-                                   path="incremental")
             return self._score_grid_incremental(pods, table, ws, now,
                                                 exclude)
-        telemetry.active().inc("scheduler_score_grid", path="rebuild")
-        inten = (self.carbon_signal.intensities(table.region, now)
-                 if self.carbon_signal is not None else None)
-        mats = decision_matrix_batch(pods, table, carbon_intensity=inten)
-        valid = table.fits(np.asarray([p.cpu for p in pods])[:, None],
-                           np.asarray([p.mem for p in pods])[:, None])
-        if exclude is not None:
-            valid = valid & ~np.asarray(exclude, dtype=bool)
+        labels = {"backend": self.backend, "path": "rebuild"}
+        with tel.stage("scheduler_sync", **labels):
+            inten = (self.carbon_signal.intensities(table.region, now)
+                     if self.carbon_signal is not None else None)
+            mats = decision_matrix_batch(pods, table, carbon_intensity=inten)
+        with tel.stage("scheduler_mask", **labels):
+            valid = table.fits(np.asarray([p.cpu for p in pods])[:, None],
+                               np.asarray([p.mem for p in pods])[:, None])
+            if exclude is not None:
+                valid = valid & ~np.asarray(exclude, dtype=bool)
         if self.backend == "numpy":
             return topsis.closeness_grid_np(mats, ws, self._benefit, valid)
         if self.backend == "jax":
-            cc = topsis.closeness_grid(mats, ws, self._benefit, valid)
-            return np.asarray(cc)
+            with tel.stage("scheduler_dispatch", **labels):
+                cc = topsis.closeness_grid(mats, ws, self._benefit, valid)
+            return _readback(tel, cc, labels)
         if self.backend == "pallas":
             from repro.kernels import ops
-            return np.asarray(ops.topsis_closeness_grid(
-                mats, ws, self._benefit, valid=valid))
+            with tel.stage("scheduler_dispatch", **labels):
+                cc = ops.topsis_closeness_grid(mats, ws, self._benefit,
+                                               valid=valid)
+            return _readback(tel, cc, labels)
         raise ValueError(f"unknown backend {self.backend!r}; "
                          f"choose from {BACKENDS}")
 
@@ -673,12 +710,19 @@ class BatchScheduler:
         the zero-copy cache views (the reference), jax fuses gather + grid
         closeness into one dispatch on the device mirror, pallas streams
         the (P, N, C) gather through the weight-grid kernel."""
+        tel = telemetry.active()
+        labels = {"backend": self.backend, "path": "incremental"}
         cache = self._cache
-        kind_idx, dirty, carbon_moved, grew = cache.sync(pods, now)
-        valid = fleet.fits(np.asarray([p.cpu for p in pods])[:, None],
-                           np.asarray([p.mem for p in pods])[:, None])
-        if exclude is not None:
-            valid = valid & ~np.asarray(exclude, dtype=bool)
+        with tel.stage("scheduler_sync", **labels):
+            kind_idx, dirty, carbon_moved, grew = cache.sync(pods, now)
+        with tel.stage("scheduler_mask", **labels):
+            valid = fleet.fits(np.asarray([p.cpu for p in pods])[:, None],
+                               np.asarray([p.mem for p in pods])[:, None])
+            if exclude is not None:
+                valid = valid & ~np.asarray(exclude, dtype=bool)
+            p = len(pods)
+            if self.backend == "jax":
+                kind_idx, valid, _ = _pad_pod_axis(kind_idx, valid)
         if self.backend == "numpy":
             return np.stack([
                 np.stack([
@@ -688,20 +732,19 @@ class BatchScheduler:
                     for i, k in enumerate(kind_idx)])
                 for w in ws])
         if self.backend == "jax":
-            import jax.numpy as jnp
             _jit_helpers()
-            self._sync_device(cache, dirty, carbon_moved, grew)
-            p = len(pods)
-            kind_idx, valid, _ = _pad_pod_axis(kind_idx, valid)
-            cc = _closeness_grid_from_kinds(
-                self._dev, jnp.asarray(kind_idx), jnp.asarray(ws),
-                jnp.asarray(self._benefit), jnp.asarray(valid))
-            telemetry.active().inc("cache_fused_dispatches", backend="jax")
-            return np.asarray(cc[:, :p])
+            with tel.stage("scheduler_upload", **labels):
+                self._sync_device(cache, dirty, carbon_moved, grew)
+                args = _upload(tel, (kind_idx, ws, self._benefit, valid))
+            with tel.stage("scheduler_dispatch", **labels):
+                cc = _closeness_grid_from_kinds(self._dev, *args)[:, :p]
+            return _readback(tel, cc, labels)
         if self.backend == "pallas":
             from repro.kernels import ops
-            return np.asarray(ops.topsis_closeness_grid(
-                cache.mats[kind_idx], ws, self._benefit, valid=valid))
+            with tel.stage("scheduler_dispatch", **labels):
+                cc = ops.topsis_closeness_grid(cache.mats[kind_idx], ws,
+                                               self._benefit, valid=valid)
+            return _readback(tel, cc, labels)
         raise ValueError(f"unknown backend {self.backend!r}; "
                          f"choose from {BACKENDS}")
 

@@ -15,7 +15,13 @@ Design constraints (the pure-observer invariant):
 * **Disabled costs ~nothing.** The module-level default is a
   :class:`NullTelemetry` whose methods are no-ops; instrumented code calls
   ``telemetry.active()`` and never branches on whether recording is on.
-  Heavier rollups (per-node energy gauges) guard on ``tel.enabled``.
+  :meth:`~NullTelemetry.stage` spans (the layer boundaries inside a
+  scheduling round) hand back one shared no-op context when disabled.
+  Heavier rollups guard on ``tel.enabled``, and the simulation's own
+  observations (sim-time timelines sampled O(N) per clock advance, the
+  end-of-run energy rollups) on ``tel.timelines``, so a registry built
+  with ``timelines=False`` measures the round's layers without paying
+  for them.
 * **Enabled changes nothing.** Telemetry is write-only from the
   simulation's point of view: wall-clock times live only in telemetry
   output, never in sim state, so golden scenarios reproduce bitwise with
@@ -37,6 +43,16 @@ labels as keyword arguments)::
     sp.duration_s            # wall seconds, also observed into the
                              # "scheduler_decision_seconds" histogram
 
+On the device trace's clock: a registry built with ``device_trace=True``
+also enters ``jax.profiler.TraceAnnotation(name)`` for the duration of
+every span it records, so a profiler trace holds the program's own spans
+beside the device's operations. Each recorded span carries ``parent`` (its
+enclosing span's index in :attr:`Telemetry.spans`) and ``round`` (the
+registry's round counter, which every ``scheduler_batch`` /
+``scheduler_grid`` span bumps when it starts, read when the span ends), and
+:meth:`Telemetry.span_totals` sums the log per name into total and self
+time.
+
 Timelines (:class:`TimeSeries`, via :meth:`Telemetry.record`) are keyed on
 the **simulation clock**, never wall time: the recorded values are sim
 quantities (queue depths, fleet power, cumulative energy), so the same
@@ -52,7 +68,7 @@ from __future__ import annotations
 
 import math
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 __all__ = [
     "Telemetry", "NullTelemetry", "Histogram", "Span", "TimeSeries",
@@ -240,14 +256,22 @@ class TimeSeries:
                 "samples": self.samples, "max_points": self.max_points}
 
 
-class Span:
-    """One nestable timed span. A span *always* times (``duration_s`` is
-    valid after the ``with`` block even under :class:`NullTelemetry`) —
-    instrumented code reads the duration from here so wall-clock
-    measurement has one code path — but it is only *recorded* (span log +
-    ``<name>_seconds`` histogram) by an active :class:`Telemetry`."""
+# Spans that open a scheduling round: each bumps the registry's round id.
+ROUND_SPANS = frozenset({"scheduler_batch", "scheduler_grid"})
 
-    __slots__ = ("name", "labels", "t0", "duration_s", "depth", "_tel")
+_NOOP = nullcontext()
+
+
+class Span:
+    """One nestable timed span. A span from :meth:`NullTelemetry.span`
+    *always* times (``duration_s`` is valid after the ``with`` block even
+    under :class:`NullTelemetry`) — instrumented code reads the duration
+    from here so wall-clock measurement has one code path — but it is only
+    *recorded* (span log + ``<name>_seconds`` histogram) by an active
+    :class:`Telemetry`."""
+
+    __slots__ = ("name", "labels", "t0", "duration_s", "depth", "_tel",
+                 "_children", "_annotation")
 
     def __init__(self, tel: "NullTelemetry", name: str, labels: dict):
         self.name = name
@@ -256,6 +280,8 @@ class Span:
         self.duration_s = 0.0
         self.depth = 0
         self._tel = tel
+        self._children: list | None = None    # log entries of children
+        self._annotation = None
 
     def __enter__(self) -> "Span":
         self._tel._start_span(self)
@@ -273,6 +299,7 @@ class NullTelemetry:
     call sites skip building expensive rollups entirely."""
 
     enabled = False
+    timelines = False
 
     def inc(self, name: str, value: float = 1.0, **labels) -> None:
         pass
@@ -292,6 +319,13 @@ class NullTelemetry:
     def span(self, name: str, **labels) -> Span:
         return Span(self, name, labels)
 
+    def stage(self, name: str, **labels):
+        """A span that only a live registry records: the shared no-op
+        context here, so layer boundaries inside a round cost one call
+        each when recording is off. Use :meth:`span` where the caller
+        reads ``duration_s``."""
+        return _NOOP
+
     def _start_span(self, span: Span) -> None:
         pass
 
@@ -302,21 +336,37 @@ class NullTelemetry:
 class Telemetry(NullTelemetry):
     """The live registry. One instance records one run (or any scope the
     caller wants); ``snapshot()`` is the JSON-ready view the exporters
-    consume."""
+    consume.
+
+    ``timelines=False`` skips the simulation's own observations — the
+    sim-time sampling that costs O(N) Python per clock advance (the
+    engine's timelines and queue gauges, the policies' series) and the
+    power timeline's end-of-run energy gauges and series, a walk of the
+    whole ledger — and keeps counters, histograms and spans.
+    ``device_trace=True`` puts
+    every recorded span on the profiler's timeline as a
+    ``jax.profiler.TraceAnnotation`` of the span's name."""
 
     enabled = True
 
     def __init__(self,
                  latency_buckets: tuple[float, ...] = DEFAULT_LATENCY_BUCKETS,
-                 series_max_points: int = DEFAULT_SERIES_MAX_POINTS):
+                 series_max_points: int = DEFAULT_SERIES_MAX_POINTS,
+                 timelines: bool = True, device_trace: bool = False):
         self.latency_buckets = tuple(latency_buckets)
         self.series_max_points = series_max_points
+        self.timelines = timelines
+        self._annotation = None
+        if device_trace:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.counters: dict[tuple, list] = {}     # key -> [name, labels, val]
         self.gauges: dict[tuple, Gauge] = {}
         self.histograms: dict[tuple, Histogram] = {}
         self.timeseries: dict[tuple, TimeSeries] = {}
         self.spans: list[dict] = []               # completed spans, log order
         self._span_stack: list[Span] = []
+        self._round = 0
         self._epoch = time.perf_counter()
 
     # --- counters / gauges / histograms --------------------------------------
@@ -378,18 +428,56 @@ class Telemetry(NullTelemetry):
     def span(self, name: str, **labels) -> Span:
         return Span(self, name, labels)
 
+    stage = span
+
     def _start_span(self, span: Span) -> None:
+        if span.name in ROUND_SPANS:
+            self._round += 1
         span.depth = len(self._span_stack)
         self._span_stack.append(span)
+        if self._annotation is not None:
+            span._annotation = self._annotation(span.name)
+            span._annotation.__enter__()
 
     def _finish_span(self, span: Span) -> None:
+        if span._annotation is not None:
+            span._annotation.__exit__(None, None, None)
+            span._annotation = None
         if self._span_stack and self._span_stack[-1] is span:
             self._span_stack.pop()
-        self.spans.append({"name": span.name, "labels": span.labels,
-                           "start_s": span.t0 - self._epoch,
-                           "duration_s": span.duration_s,
-                           "depth": span.depth})
+        entry = {"name": span.name, "labels": span.labels,
+                 "start_s": span.t0 - self._epoch,
+                 "duration_s": span.duration_s,
+                 "depth": span.depth, "parent": None, "round": self._round}
+        index = len(self.spans)
+        self.spans.append(entry)
+        # children finish first: point them at this span's log index now
+        for child in span._children or ():
+            child["parent"] = index
+        span._children = None
+        if self._span_stack:
+            outer = self._span_stack[-1]
+            if outer._children is None:
+                outer._children = []
+            outer._children.append(entry)
         self.observe(f"{span.name}_seconds", span.duration_s, **span.labels)
+
+    def span_totals(self) -> dict[str, dict]:
+        """Per span name over the whole log: ``count``, ``total_s`` (summed
+        durations) and ``self_s`` (summed durations less those of each
+        span's direct children)."""
+        out: dict[str, dict] = {}
+        for e in self.spans:
+            t = out.setdefault(e["name"], {"count": 0, "total_s": 0.0,
+                                           "self_s": 0.0})
+            t["count"] += 1
+            t["total_s"] += e["duration_s"]
+            t["self_s"] += e["duration_s"]
+        for e in self.spans:
+            if e["parent"] is not None:
+                out[self.spans[e["parent"]]["name"]]["self_s"] -= \
+                    e["duration_s"]
+        return out
 
     # --- export --------------------------------------------------------------
     def snapshot(self) -> dict:
