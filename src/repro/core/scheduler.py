@@ -111,6 +111,41 @@ def _score(mat: np.ndarray, weights: np.ndarray, valid: np.ndarray,
     raise ValueError(f"unknown backend {backend!r}; choose from {BACKENDS}")
 
 
+def _take_rows(a: np.ndarray, idx) -> np.ndarray:
+    """``a[idx]`` of a 2-D array, gathered in the array's own memory order:
+    the device hands the scores back pod-minor (Fortran order), where a
+    row-wise gather strides across the whole matrix."""
+    if a.flags.f_contiguous and not a.flags.c_contiguous:
+        return np.take(a.T, idx, axis=1).T
+    return np.take(a, idx, axis=0)
+
+
+def _rank_groups(cc: np.ndarray, requests: list) -> "tuple[list, list]":
+    """Group the queue's pods that share one ranking: rows of ``cc`` equal
+    byte for byte and equal ``(cpu, mem)`` requests. Returns ``(group,
+    reps)``: pod i's group index and each group's first pod. Each pod is
+    compared with the first pod of its request whose row has the same XOR
+    fold of its bits; a pod whose row still differs is grouped on its
+    row's bytes."""
+    bits = cc[:len(requests)].view(np.dtype(f"u{cc.itemsize}"))
+    folds = np.bitwise_xor.reduce(bits, axis=1).tolist()
+    first: dict = {}
+    rep_of = [first.setdefault((folds[i], req), i)
+              for i, req in enumerate(requests)]
+    same = (bits == _take_rows(bits, rep_of)).all(axis=1).tolist()
+    group: list[int] = []
+    reps: list[int] = []
+    ids: dict = {}
+    for i, req in enumerate(requests):
+        key = rep_of[i] if same[i] else (req, bits[i].tobytes())
+        g = ids.get(key)
+        if g is None:
+            g = ids[key] = len(reps)
+            reps.append(i)
+        group.append(g)
+    return group, reps
+
+
 def _greedy_assign(cc: np.ndarray, pods: Sequence[Pod], table: NodeTable,
                    blocked=None) -> "list[int | None]":
     """Commit one (P, N) closeness matrix greedily in queue order against a
@@ -118,29 +153,59 @@ def _greedy_assign(cc: np.ndarray, pods: Sequence[Pod], table: NodeTable,
     fits (``blocked[i]`` optionally forbids one node index for ``pods[i]``).
     Extracted from :meth:`BatchScheduler.select_many` so the grid path
     commits every scheme through identical code — the per-scheme ledgers
-    are independent what-if placements off the same snapshot."""
+    are independent what-if placements off the same snapshot.
+
+    Pods with the same row and request (:func:`_rank_groups`) share one
+    stable descending argsort and one cursor into it: when no request is
+    negative, free capacity only shrinks within a call, so a node that
+    fails a group's fit test fails it for the rest of the call and the
+    cursor moves past it for good. A ``-inf`` score ends a scan; a node
+    blocked for one pod that still fits holds the cursor for the rest of
+    the group. Placements are those of sorting and walking every row from
+    rank 0."""
     tel = telemetry.active()
+    requests = [(pod.cpu, pod.mem) for pod in pods]
     with tel.stage("scheduler_argsort"):
-        order = np.argsort(-cc, kind="stable", axis=-1)
+        group, reps = _rank_groups(cc, requests)
+        order = np.argsort(-_take_rows(cc, reps), kind="stable",
+                           axis=-1)
     with tel.stage("scheduler_walk"):
         free_cpu = table.free_cpu.copy()
         free_mem = table.free_mem.copy()
+        shrinks = all(c >= 0 and m >= 0 for c, m in requests)
+        ranks = list(order)
+        scores = [cc[i] for i in reps]
+        cursor = [0] * len(reps)
+        n = cc.shape[-1]
+        neg_inf = -np.inf
+        steps = 0
         assignments: list[int | None] = []
-        for i, pod in enumerate(pods):
+        for i, (cpu, mem) in enumerate(requests):
+            g = group[i]
+            rank, score = ranks[g], scores[g]
             forbid = blocked[i] if blocked is not None else None
+            need_cpu, need_mem = cpu - 1e-9, mem - 1e-9
+            pos = start = cursor[g]
+            advance = shrinks
             chosen = None
-            for j in order[i]:
-                if np.isneginf(cc[i, j]):
+            while pos < n:
+                j = rank[pos]
+                if score[j] == neg_inf:
                     break           # rest of the ranking is infeasible
-                if forbid is not None and int(j) == forbid:
-                    continue
-                if free_cpu[j] >= pod.cpu - 1e-9 \
-                        and free_mem[j] >= pod.mem - 1e-9:
-                    chosen = int(j)
-                    free_cpu[j] -= pod.cpu
-                    free_mem[j] -= pod.mem
-                    break
+                if free_cpu[j] >= need_cpu and free_mem[j] >= need_mem:
+                    if forbid is None or j != forbid:
+                        chosen = int(j)
+                        free_cpu[j] -= cpu
+                        free_mem[j] -= mem
+                        break
+                    advance = False     # blocked for this pod only
+                elif advance:
+                    cursor[g] = pos + 1
+                pos += 1
+            steps += pos - start + (pos < n)
             assignments.append(chosen)
+    tel.inc("scheduler_rank_groups", value=float(len(reps)))
+    tel.inc("scheduler_walk_steps", value=float(steps))
     return assignments
 
 

@@ -39,13 +39,14 @@ except ModuleNotFoundError:
 
     st = _AnyStrategy()
 
-from repro.core import topsis
+from repro.core import telemetry, topsis
 from repro.core.criteria import benefit_mask
 from repro.core.scheduler import (BatchScheduler, GreenPodScheduler,
-                                  decision_matrix, decision_matrix_batch)
+                                  _greedy_assign, decision_matrix,
+                                  decision_matrix_batch)
 from repro.core.weighting import SCHEME_NAMES
 from repro.cluster.node import Node, NodeTable, make_fleet, make_paper_cluster
-from repro.cluster.workload import WORKLOADS, Pod
+from repro.cluster.workload import WORKLOADS, Pod, WorkloadSpec
 from repro.kernels import ops
 
 BENEFIT = benefit_mask()
@@ -345,3 +346,161 @@ def test_property_backends_equivalent(seed, n, p, util):
         finite = np.isfinite(want)
         np.testing.assert_array_equal(finite, np.isfinite(got))
         np.testing.assert_allclose(got[finite], want[finite], atol=1e-5)
+
+
+# --- ranking groups in the greedy commit --------------------------------------
+def _oracle_greedy_assign(cc, pods, table, blocked=None):
+    """The commit before ranking groups, verbatim but for its spans and a
+    count of the ranking entries it examines: every row sorted and walked
+    from rank 0. Returns ``(assignments, examined)``."""
+    examined = 0
+    order = np.argsort(-cc, kind="stable", axis=-1)
+    free_cpu = table.free_cpu.copy()
+    free_mem = table.free_mem.copy()
+    assignments: list[int | None] = []
+    for i, pod in enumerate(pods):
+        forbid = blocked[i] if blocked is not None else None
+        chosen = None
+        for j in order[i]:
+            examined += 1
+            if np.isneginf(cc[i, j]):
+                break           # rest of the ranking is infeasible
+            if forbid is not None and int(j) == forbid:
+                continue
+            if free_cpu[j] >= pod.cpu - 1e-9 \
+                    and free_mem[j] >= pod.mem - 1e-9:
+                chosen = int(j)
+                free_cpu[j] -= pod.cpu
+                free_mem[j] -= pod.mem
+                break
+        assignments.append(chosen)
+    return assignments, examined
+
+
+_ODD = WorkloadSpec("odd", -0.8, 0.5, 10.0, "negative cpu request")
+
+
+def _commit_case(seed):
+    """A random commit input built to stress ranking groups: a few base
+    rows reused across pods (duplicates, and one row under several
+    requests, and rows that are permutations of each other), scores from
+    a few levels (exact ties at different indices, 0.0 beside -0.0), -inf
+    tails and wholly -inf rows, now and then a NaN, a negative request,
+    nodes small enough to fill mid-queue, ``blocked`` entries that sit on
+    the node the unblocked walk would take, in C or Fortran order."""
+    rng = np.random.default_rng(seed)
+    n, p = int(rng.integers(1, 24)), int(rng.integers(1, 40))
+    nodes = [Node(f"n{j}", "ABC"[j % 3], float(rng.choice([0.5, 1.0, 1.2, 2.0])),
+                  float(rng.choice([1.0, 2.0, 2.5, 4.0]))) for j in range(n)]
+    table = NodeTable.from_nodes(nodes)
+    levels = np.array([0.0, -0.0, 0.25, 0.5, 0.5, 0.9, -np.inf])
+    if rng.random() < 0.2:
+        levels = np.append(levels, np.nan)
+    base = rng.choice(levels, size=(int(rng.integers(1, 5)), n))
+    base[rng.random(len(base)) < 0.2] = -np.inf
+    if rng.random() < 0.3:
+        base = np.vstack([base, rng.permutation(base[0])])
+    dtype = np.float32 if rng.random() < 0.5 else np.float64
+    cc = base[rng.integers(len(base), size=p)].astype(dtype)
+    if rng.random() < 0.5:
+        cc = np.asfortranarray(cc)         # the device's readback layout
+    specs = list(WORKLOADS.values()) + ([_ODD] if rng.random() < 0.2 else [])
+    pods = [Pod(i, specs[int(rng.integers(len(specs)))], "topsis")
+            for i in range(p)]
+    blocked = None
+    if rng.random() < 0.6:
+        free, _ = _oracle_greedy_assign(cc, pods, table)
+        blocked = [a if a is not None and rng.random() < 0.4
+                   else (int(rng.integers(n)) if rng.random() < 0.2 else None)
+                   for a in free]
+    return cc, pods, table, blocked
+
+
+def _assert_commit_matches_oracle(seed):
+    cc, pods, table, blocked = _commit_case(seed)
+    want, _ = _oracle_greedy_assign(cc, pods, table, blocked=blocked)
+    assert _greedy_assign(cc, pods, table, blocked=blocked) == want
+
+
+@settings(max_examples=200, deadline=None)
+@given(seed=st.integers(0, 2 ** 63 - 1))
+def test_property_grouped_commit_matches_full_argsort_walk(seed):
+    """Sorting each distinct (row, request) once and walking it with a
+    shared cursor places every pod where sorting and walking every row
+    from rank 0 does."""
+    _assert_commit_matches_oracle(seed)
+
+
+@pytest.mark.parametrize("seed", range(16))
+def test_grouped_commit_matches_full_argsort_walk_seeded(seed):
+    """The property above on fixed seeds, so it runs without hypothesis."""
+    _assert_commit_matches_oracle(2 ** 40 + 7919 * seed)
+
+
+def test_blocked_node_at_the_cursor_stays_for_the_group():
+    """A node blocked for one pod that still fits is the next pod's pick:
+    the group's cursor must not pass it."""
+    table = NodeTable.from_nodes([Node("a", "A", 2.0, 4.0),
+                                  Node("b", "B", 2.0, 4.0)])
+    pods = [Pod(i, WORKLOADS["complex"], "topsis") for i in range(3)]
+    cc = np.array([[0.9, 0.5]] * 3)
+    got = _greedy_assign(cc, pods, table, blocked=[0, None, None])
+    assert got == [1, 0, 0]
+    assert got == _oracle_greedy_assign(cc, pods, table, [0, None, None])[0]
+
+
+def test_rows_that_fold_alike_stay_apart():
+    """Two rows holding the same scores in another order (equal XOR folds
+    of their bits) are two rankings."""
+    table = NodeTable.from_nodes([Node(f"n{j}", "C", 4.0, 16.0)
+                                  for j in range(3)])
+    pods = [Pod(i, WORKLOADS["light"], "topsis") for i in range(2)]
+    cc = np.array([[0.9, 0.5, 0.25], [0.25, 0.5, 0.9]], dtype=np.float32)
+    assert _greedy_assign(cc, pods, table) == [0, 2]
+
+
+def test_negative_request_gives_capacity_back():
+    """A negative request frees capacity within the call, so a node that
+    failed a group's fit test can fit it again: no cursor then skips it."""
+    table = NodeTable.from_nodes([Node("a", "C", 1.2, 8.0),
+                                  Node("b", "B", 1.0, 2.0)])
+    pods = [Pod(0, WORKLOADS["complex"], "topsis"),
+            Pod(1, WORKLOADS["complex"], "topsis"),
+            Pod(2, _ODD, "topsis"),
+            Pod(3, WORKLOADS["complex"], "topsis")]
+    cc = np.array([[0.9, 0.5]] * 4)
+    want, _ = _oracle_greedy_assign(cc, pods, table)
+    assert want == [0, 1, 0, 0]
+    assert _greedy_assign(cc, pods, table) == want
+
+
+def test_rank_group_and_walk_step_counters():
+    """``scheduler_rank_groups`` counts distinct (row, request) rankings a
+    call, ``scheduler_walk_steps`` the ranking entries it examined, which
+    the shared cursor keeps at most the full walk's; a registry records
+    nothing once it is switched off."""
+    table = make_fleet(64, seed=6, utilization=0.3)
+    kinds = list(WORKLOADS.values())
+    pods = [Pod(i, kinds[i % 3], "topsis") for i in range(48)]
+    sched = BatchScheduler("energy_centric", backend="numpy")
+    with telemetry.enabled(telemetry.Telemetry(timelines=False)) as tel:
+        _, diag = sched.select_many(pods, table)
+    assert tel.counter_value("scheduler_rank_groups") == 3
+    _, examined = _oracle_greedy_assign(diag["closeness"], pods, table)
+    steps = tel.counter_value("scheduler_walk_steps")
+    assert 0 < steps <= examined
+
+    rng = np.random.default_rng(0)
+    cc = rng.random((20, 64))                   # every row distinct
+    with telemetry.enabled(telemetry.Telemetry(timelines=False)) as tel:
+        _greedy_assign(cc, pods[:20], table)
+        # 0.0 and -0.0 differ in their bytes: two groups, one placement
+        pair = [Pod(i, WORKLOADS["light"], "topsis") for i in range(2)]
+        signed = np.array([[0.0, 1.0], [-0.0, 1.0]])
+        assert _greedy_assign(signed, pair, table) == [1, 1]
+    assert tel.counter_value("scheduler_rank_groups") == 20 + 2
+
+    assert telemetry.active() is telemetry.NULL
+    before = {k: v[2] for k, v in tel.counters.items()}
+    _greedy_assign(cc, pods[:20], table)
+    assert {k: v[2] for k, v in tel.counters.items()} == before
