@@ -6,7 +6,8 @@ in one process:
 runs the program on ``--seeds`` seeds (the lower readings), the control
 (the reference in bfloat16 in the program's place) and every planted
 fault of ``faults.py`` on ``--control-seeds`` seeds each (the upper
-readings). Each run is a benchmark run whose window is the first replay
+readings); the policy faults only where the cell's configuration declares
+``policies``. Each run is a benchmark run whose window is the first replay
 alone. One JSON line per run: what ran, on which seed, and every number
 the check compares. Not part of a benchmark run; run it where the cell
 runs, on the chip.
@@ -38,8 +39,11 @@ def main(argv=None) -> int:
     plans = [("program", None, args.seeds),
              ("control", lambda: faults.control(cell.config),
               args.control_seeds)]
+    named = dict(faults.FAULTS)
+    if cell.config.get("policies"):
+        named.update(faults.POLICY_FAULTS)
     plans += [(name, make, args.control_seeds)
-              for name, make in faults.FAULTS.items()]
+              for name, make in named.items()]
     only = set(filter(None, args.only.split(",")))
     for name, make, n in plans:
         if only and name not in only:

@@ -13,11 +13,23 @@ run; none is used by a benchmark run itself (see ``calibrate.py`` and
 * ``dropped_commit``: every 97th placement is dropped where the engine
   commits it, so its pod is never placed.
 * ``energy_skew``: the program's power ledger books 1% more dynamic power.
+
+Faults of the carbon and autoscale policies (a cell whose configuration
+declares ``policies``):
+
+* ``never_defer``: the carbon policy holds no pod back.
+* ``never_sleep``: the autoscaler's idle timeout is infinite.
+* ``never_preempt``: the carbon policy preempts nothing at a round's start.
+* ``carbon_blind``: the schedulers' carbon-rate column reads zero.
+* ``wake_storm``: every queue-pressure pass wakes every sleeping node.
+* ``drain_busy``: consolidation drains nodes at any utilisation.
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
+import math
 
 import numpy as np
 
@@ -48,16 +60,20 @@ class _FleetView:
 
 
 def control(cfg):
+    """The reference in bfloat16 on ``score_queue``'s own inputs: the
+    fleet's load and awake column, the round's time and the engine's
+    exclusion masks."""
     import jax.numpy as jnp
 
     import reference
     from repro.core.scheduler import BatchScheduler
 
     def make(orig):
-        def score_queue(sched, pods, nodes, *a, **kw):
+        def score_queue(sched, pods, nodes, now=0.0, exclude=None):
             cc = reference.score_round(
                 cfg, _FleetView(nodes), nodes.used_cpu, nodes.used_mem, pods,
-                xp=jnp, dtype=jnp.bfloat16)
+                now=now, awake=nodes.awake, exclude=exclude, xp=jnp,
+                dtype=jnp.bfloat16)
             return cc.astype(np.float32)
         return score_queue
     return _patched(BatchScheduler, "score_queue", make)
@@ -131,6 +147,68 @@ def energy_skew():
     return _patched(PowerTimeline, "add", make)
 
 
+def never_defer():
+    from repro.core.carbon import CarbonScheduling
+    return _patched(CarbonScheduling, "filter_pending",
+                    lambda orig: lambda policy, sim, pods, t: [])
+
+
+def _autoscale_knobs(**changes):
+    """The autoscaler runs with ``changes`` to the cell's settings."""
+    from repro.core.elastic import AutoscaleScheduling
+
+    def make(orig):
+        def init(policy, knobs):
+            orig(policy, dataclasses.replace(knobs, **changes))
+        return init
+    return _patched(AutoscaleScheduling, "__init__", make)
+
+
+def never_sleep():
+    return _autoscale_knobs(idle_timeout_s=math.inf)
+
+
+def never_preempt():
+    from repro.core.carbon import CarbonScheduling
+    return _patched(CarbonScheduling, "on_round_start",
+                    lambda orig: lambda policy, sim, t: None)
+
+
+def carbon_blind():
+    from repro.core.carbon import CarbonSignal
+
+    def make(orig):
+        def intensities(signal, regions, t):
+            return np.zeros(len(regions))
+        return intensities
+    return _patched(CarbonSignal, "intensities", make)
+
+
+def wake_storm():
+    from repro.core.elastic import ASLEEP, ElasticFleet
+
+    def make(orig):
+        def wake_for_pressure(fleet, sched, pods, t):
+            woken = orig(fleet, sched, pods, t)
+            if pods and fleet.policy.wake_on_pressure:
+                for i, state in enumerate(fleet.states(t)):
+                    if state == ASLEEP:
+                        fleet.request_wake(i, t)
+                        woken.append(i)
+            return woken
+        return wake_for_pressure
+    return _patched(ElasticFleet, "wake_for_pressure", make)
+
+
+def drain_busy():
+    return _autoscale_knobs(consolidate_util_below=1.0)
+
+
 FAULTS = {"stale_state": stale_state, "half_batch": half_batch,
           "altered_answer": altered_answer, "dropped_commit": dropped_commit,
           "energy_skew": energy_skew}
+
+POLICY_FAULTS = {"never_defer": never_defer, "never_sleep": never_sleep,
+                 "never_preempt": never_preempt,
+                 "carbon_blind": carbon_blind, "wake_storm": wake_storm,
+                 "drain_busy": drain_busy}

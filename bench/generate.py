@@ -5,8 +5,9 @@ from the configuration's and the traffic file's data, so a change to the
 program's built-in tables cannot move the yardstick. Every seed gets the
 same work in another order: the same multiset of node classes and sizes,
 the same burst gaps (quantiles of the exponential gap distribution) and the
-same kind counts in every burst. What the seed changes is which node is which, the order of the gaps and
-the order of pods within a burst.
+same kind counts in every burst, with the traffic's optional ``deferrable``
+share split within each kind. What the seed changes is which node is
+which, the order of the gaps and the order of pods within a burst.
 """
 from __future__ import annotations
 
@@ -74,8 +75,22 @@ def workload_specs(cfg: dict) -> dict:
             for k, v in cfg["pod_kinds"].items()}
 
 
+def burst_pool(traffic: dict) -> list:
+    """One burst's ``(kind, deferrable)`` multiset, the same in every burst
+    on every seed: the kind counts split ``mix``, and the traffic's
+    optional ``deferrable`` share is split within each kind."""
+    size = int(traffic["burst_size"])
+    share = float(traffic.get("deferrable", {}).get("share", 0.0))
+    pool = []
+    for kind, count in _quota(traffic["mix"], size).items():
+        split = _quota({True: share, False: 1.0 - share}, count)
+        pool += [(kind, d) for d in (True, False) for _ in range(split[d])]
+    return pool
+
+
 def bursts(cfg: dict, traffic: dict, seed: int) -> list:
-    """``[(t_arrival_s, [Pod, ...]), ...]`` for one replay."""
+    """``[(t_arrival_s, [Pod, ...]), ...]`` for one replay. A deferrable
+    pod carries the traffic's ``deferrable.deadline_s``."""
     from repro.cluster.workload import Pod
     specs = workload_specs(cfg)
     rng = np.random.default_rng(seed)
@@ -84,28 +99,29 @@ def bursts(cfg: dict, traffic: dict, seed: int) -> list:
     gaps = [-math.log(1.0 - (k + 0.5) / n_bursts) / rate
             for k in range(n_bursts)]
     gaps = [gaps[i] for i in rng.permutation(n_bursts)]
-    kind_counts = _quota(traffic["mix"], size)
-    pool = [k for k, c in kind_counts.items() for _ in range(c)]
+    pool = burst_pool(traffic)
+    extra = {}
+    if "deferrable" in traffic:
+        extra = {"deadline_s": float(traffic["deferrable"]["deadline_s"])}
     out, uid, t = [], 0, 0.0
     for gap in gaps:
         t += gap
-        kinds = [pool[i] for i in rng.permutation(size)]
-        pods = [Pod(uid + i, specs[kinds[i]], traffic["scheduler"])
-                for i in range(size)]
+        drawn = [pool[i] for i in rng.permutation(size)]
+        pods = [Pod(uid + i, specs[kind], traffic["scheduler"],
+                    deferrable=d, **extra)
+                for i, (kind, d) in enumerate(drawn)]
         uid += size
         out.append((t, pods))
     return out
 
 
-def arrivals(cfg: dict, traffic: dict, seed: int):
-    """The replay's bursts as the program's ``ArrivalProcess``."""
+def arrivals(events: list):
+    """A replay's bursts (``bursts``) as the program's
+    ``ArrivalProcess``."""
     from repro.cluster.workload import ArrivalProcess
 
     class Replay(ArrivalProcess):
-        def __init__(self):
-            self._events = bursts(cfg, traffic, seed)
-
         def events(self):
-            return self._events
+            return events
 
     return Replay()

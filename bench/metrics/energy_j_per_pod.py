@@ -1,5 +1,7 @@
-"""Whole-fleet energy of the first replay (seed ``--seed``), which always
-runs to its end, over the pods it placed."""
+"""Fleet energy of the first replay (seed ``--seed``), which always runs
+to its end, over the pods it placed: the plain reference's
+``reference_policies.Ledger.fleet_energy_j`` from the program's
+placements, evictions and wakes."""
 
 
 def read(ctx):

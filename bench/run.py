@@ -4,10 +4,13 @@
 
 run from the root of a checkout on a machine with the chips the cell asks
 for. Everything a cell is made of is found by name: the cell in
-``BENCHMARK.json``, its configuration in ``bench/configs/<config>.json``,
-its traffic in ``bench/traffic/<traffic>.json``, the limits of its
-correctness check in ``bench/limits/<cell>.json`` and each metric's reader
-in ``bench/metrics/<metric>.py``.
+``BENCHMARK.json``, its configuration in ``bench/configs/<config>.json``
+(with an optional ``policies`` key: the carbon signal and thresholds, the
+autoscaler's settings and the per-class wake profiles), its traffic in
+``bench/traffic/<traffic>.json`` (with an optional ``deferrable`` share
+and deadline), the limits of its correctness check in
+``bench/limits/<cell>.json`` and each metric's reader in
+``bench/metrics/<metric>.py``.
 
 With ``--trace 0`` the last line of standard output is one JSON object
 with the cell's end-to-end metrics; with ``--trace 1`` the window runs
@@ -26,6 +29,7 @@ T_PROCESS = time.perf_counter()
 
 import argparse                          # noqa: E402
 import contextlib                        # noqa: E402
+import ctypes                            # noqa: E402
 import gc                                # noqa: E402
 import importlib.util                    # noqa: E402
 import json                              # noqa: E402
@@ -48,6 +52,24 @@ def use_cache_dir() -> None:
     directory at every write. Call before JAX is imported."""
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
     os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = "-1"
+
+
+def steady_heap() -> None:
+    """Fix glibc's heap thresholds for the process: blocks under 32 MiB
+    come from the heap, which grows 64 MiB at a time and is not given
+    back below 1 GiB. At glibc's adaptive defaults a run that loads its
+    programs from the cache starts its window on a small heap, which is
+    trimmed and faulted in again while it grows: on a TPU v5e host the
+    rounds of the window's first 10 to 30 seconds then read a 95th
+    percentile of 14 to 19 ms against 12 once the heap has settled, or
+    where set-up compiled and grew the heap first. Call before the heap
+    grows."""
+    mallopt = ctypes.CDLL("libc.so.6").mallopt
+    for param, value in ((-3, 32 << 20),     # M_MMAP_THRESHOLD
+                         (-1, 1 << 30),      # M_TRIM_THRESHOLD
+                         (-2, 64 << 20)):    # M_TOP_PAD
+        if not mallopt(param, value):
+            raise OSError(f"mallopt({param}, {value}) failed")
 
 
 def load_cell(name: str) -> SimpleNamespace:
@@ -89,7 +111,6 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
 
     import generate
     import harness
-    import reference
     from compile_counter import CompileCounter
     from repro.device import enable_compile_cache
 
@@ -98,7 +119,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
     counter = CompileCounter()
     cfg, traffic = cell.config, cell.traffic
     dev = jax.devices()[0]
-    rec = harness.Recorder(seed, trace)
+    rec = harness.Recorder(seed, trace, policies="policies" in cfg)
     with contextlib.ExitStack() as stack:
         for p in patches:
             stack.enter_context(p)
@@ -131,11 +152,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
         reduced = xplane.reduce(devices, spans)
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
+    del fleet0
+    numbers = harness.check(cfg, traffic, rec, win)
     energy = None
-    for replay, fleet, res in win.results:
-        placed = len({r.pod.uid for r in res.records})
-        if replay == 0 and placed:
-            energy = reference.task_energy_j(res.records, fleet) / placed
+    if numbers["placed_first"]:
+        energy = numbers["fleet_energy_j"] / numbers["placed_first"]
     rounds = rec.rounds
     ctx = SimpleNamespace(
         setup_s=setup_s, window_s=win.t_end - win.t_start,
@@ -143,15 +164,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float,
         rounds=rounds, n_rounds=len(rounds),
         select_s=sum(r.select_s for r in rounds),
         score_s=sum(r.score_s for r in rounds),
-        n_nodes=len(fleet0), n_criteria=len(cfg["criteria"]),
+        n_nodes=len(rec.fleets[0]), n_criteria=len(cfg["criteria"]),
         device_kind=dev.device_kind, trace=reduced, energy_j_per_pod=energy)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = reader(m["name"])(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    del fleet0
-    numbers = harness.check(cfg, traffic, rec, win)
     checks = {k: {"value": numbers[k], "limit": cell.limits[k]}
               for k in cell.limits}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
@@ -186,6 +205,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
+    steady_heap()
     if not (ROOT / "src" / "repro").is_dir():
         print("bench: the program (src/repro) is not in this checkout",
               file=sys.stderr)

@@ -45,3 +45,22 @@ def test_bursts_same_work_other_order():
     assert uids == list(range(len(uids)))
     assert [p.workload.kind for p in a[0][1]] \
         != [p.workload.kind for p in b[0][1]]
+
+
+def test_deferrable_share_same_in_every_burst_and_seed():
+    traffic = json.loads((BENCH / "tests" / "data" / "policy-200.traffic.json")
+                         .read_text())
+    a, b = (generate.bursts(CFG, traffic, s) for s in SEEDS)
+    deadline = traffic["deferrable"]["deadline_s"]
+    for _, pods in a + b:
+        held = Counter(p.workload.kind for p in pods if p.deferrable)
+        assert held == {"light": 8, "medium": 5, "complex": 3}
+        assert all(p.deadline_s == deadline for p in pods if p.deferrable)
+    assert [p.deferrable for p in a[0][1]] != [p.deferrable for p in b[0][1]]
+    # without the key the draw is the steady traffic's, pod for pod
+    plain = dict(traffic)
+    del plain["deferrable"]
+    c = generate.bursts(CFG, plain, SEEDS[0])
+    assert [(t, [p.workload.kind for p in pods]) for t, pods in c] \
+        == [(t, [p.workload.kind for p in pods]) for t, pods in a]
+    assert not any(p.deferrable for _, pods in c for p in pods)
