@@ -366,35 +366,41 @@ class ElasticFleet:
         not covered by capacity woken earlier in this pass wakes the
         TOPSIS-best sleeping node that fits it (scored by the run's own
         scheduler — same 6-criteria stack, any backend). Returns the woken
-        node indices."""
+        node indices. A live registry records the pass as a
+        ``policy_wake_pass`` span and each probe for a node as a
+        ``policy_wake_probe`` span and a ``policy_wake_probes`` count."""
         if not self.policy.wake_on_pressure:
             return []
-        asleep = np.asarray([s == ASLEEP for s in self.states(t)])
-        if not asleep.any():
-            return []
-        woken: list[int] = []
-        free: dict[int, list[float]] = {}
-        for pod in pods:
-            covered = False
-            for j in woken:
-                if free[j][0] >= pod.cpu - 1e-9 and free[j][1] >= pod.mem - 1e-9:
-                    free[j][0] -= pod.cpu
-                    free[j][1] -= pod.mem
-                    covered = True
-                    break
-            if covered:
-                continue
-            idx = _best_node(sched, pod,
-                             self.table if self.table is not None
-                             else self.nodes, t, exclude=~asleep)
-            if idx is None:
-                continue                 # fits no sleeping node either
-            self.request_wake(idx, t)
-            asleep[idx] = False
-            woken.append(idx)
-            free[idx] = [self.nodes[idx].free_cpu - pod.cpu,
-                         self.nodes[idx].free_mem - pod.mem]
-        return woken
+        tel = telemetry.active()
+        with tel.stage("policy_wake_pass"):
+            asleep = np.asarray([s == ASLEEP for s in self.states(t)])
+            if not asleep.any():
+                return []
+            woken: list[int] = []
+            free: dict[int, list[float]] = {}
+            for pod in pods:
+                covered = False
+                for j in woken:
+                    if free[j][0] >= pod.cpu - 1e-9 and free[j][1] >= pod.mem - 1e-9:
+                        free[j][0] -= pod.cpu
+                        free[j][1] -= pod.mem
+                        covered = True
+                        break
+                if covered:
+                    continue
+                tel.inc("policy_wake_probes", policy="AutoscaleScheduling")
+                with tel.stage("policy_wake_probe"):
+                    idx = _best_node(sched, pod,
+                                     self.table if self.table is not None
+                                     else self.nodes, t, exclude=~asleep)
+                if idx is None:
+                    continue                 # fits no sleeping node either
+                self.request_wake(idx, t)
+                asleep[idx] = False
+                woken.append(idx)
+                free[idx] = [self.nodes[idx].free_cpu - pod.cpu,
+                             self.nodes[idx].free_mem - pod.mem]
+            return woken
 
     def consolidation_victims(self, t: float, running: Sequence,
                               deadline_of: Callable) -> tuple[list[int],

@@ -217,11 +217,25 @@ def _upload(tel, arrays):
     return out
 
 
-def _readback(tel, cc, labels: dict) -> np.ndarray:
-    """Wait for the device's scores and copy them to the host."""
+def _readback(tel, cc, labels: dict, p: int | None = None) -> np.ndarray:
+    """Wait for the device's scores and copy them to the host, then keep
+    the first ``p`` pod rows (the pod axis is the second to last). The
+    device array keeps its padded pod axis: slicing it there would
+    compile one program per distinct queue length, so the padding rows
+    are dropped on the host. ``scheduler_readback_bytes`` counts the
+    padded bytes copied.
+
+    The rows kept are copied into an array of their own, in the
+    readback's memory order: a view of the first ``p`` rows of the
+    pod-minor (Fortran-order) padded matrix is contiguous in neither
+    order, and the commit's row gathers and reductions on such a view
+    (``_rank_groups``, ``_take_rows``) stride across the whole matrix."""
     with tel.stage("scheduler_readback", **labels):
         out = np.asarray(cc)
-    tel.inc("scheduler_readback_bytes", value=float(out.nbytes))
+        nbytes = out.nbytes
+        if p is not None and p != out.shape[-2]:
+            out = out[..., :p, :].copy(order="K")
+    tel.inc("scheduler_readback_bytes", value=float(nbytes))
     return out
 
 
@@ -405,7 +419,7 @@ def _pad_pod_axis(rows: np.ndarray, valid: np.ndarray,
     of compiling one per queue length. ``rows`` — the (P, N, C) criteria
     tensor or the (P,) kind index — pads with zeros, ``ws`` with ones, and
     ``valid`` with all-False rows: padding pods are infeasible everywhere,
-    score -inf, and the caller slices them off."""
+    score -inf, and :func:`_readback` drops them on the host."""
     pad = _pow2_pad_len(len(valid)) - len(valid)
     if pad:
         rows = np.concatenate(
@@ -603,14 +617,14 @@ class BatchScheduler:
             with tel.stage("scheduler_upload", **labels):
                 args = _upload(tel, (mats, ws, self._benefit, valid))
             with tel.stage("scheduler_dispatch", **labels):
-                cc = topsis.batched_closeness_cc(*args)[:p]
-            return _readback(tel, cc, labels)
+                cc = topsis.batched_closeness_cc(*args)
+            return _readback(tel, cc, labels, p)
         if self.backend == "pallas":
             from repro.kernels import ops
             with tel.stage("scheduler_dispatch", **labels):
                 cc = ops.topsis_closeness_batched(
-                    mats, ws, self._benefit, valid=valid)[:p]
-            return _readback(tel, cc, labels)
+                    mats, ws, self._benefit, valid=valid)
+            return _readback(tel, cc, labels, p)
         raise ValueError(f"unknown backend {self.backend!r}; "
                          f"choose from {BACKENDS}")
 
@@ -651,15 +665,15 @@ class BatchScheduler:
                 self._sync_device(cache, dirty, carbon_moved, grew)
                 args = _upload(tel, (kind_idx, ws, self._benefit, valid))
             with tel.stage("scheduler_dispatch", **labels):
-                cc = _closeness_from_kinds(self._dev, *args)[:p]
-            return _readback(tel, cc, labels)
+                cc = _closeness_from_kinds(self._dev, *args)
+            return _readback(tel, cc, labels, p)
         if self.backend == "pallas":
             from repro.kernels import ops
             with tel.stage("scheduler_dispatch", **labels):
                 cc = ops.topsis_closeness_kinds(
                     cache.mats, kind_idx, ws, self._benefit,
-                    valid=valid)[:p]
-            return _readback(tel, cc, labels)
+                    valid=valid)
+            return _readback(tel, cc, labels, p)
         raise ValueError(f"unknown backend {self.backend!r}; "
                          f"choose from {BACKENDS}")
 
@@ -802,8 +816,8 @@ class BatchScheduler:
                 self._sync_device(cache, dirty, carbon_moved, grew)
                 args = _upload(tel, (kind_idx, ws, self._benefit, valid))
             with tel.stage("scheduler_dispatch", **labels):
-                cc = _closeness_grid_from_kinds(self._dev, *args)[:, :p]
-            return _readback(tel, cc, labels)
+                cc = _closeness_grid_from_kinds(self._dev, *args)
+            return _readback(tel, cc, labels, p)
         if self.backend == "pallas":
             from repro.kernels import ops
             with tel.stage("scheduler_dispatch", **labels):

@@ -504,3 +504,79 @@ def test_rank_group_and_walk_step_counters():
     before = {k: v[2] for k, v in tel.counters.items()}
     _greedy_assign(cc, pods[:20], table)
     assert {k: v[2] for k, v in tel.counters.items()} == before
+
+
+# --- queue lengths that compile nothing new ----------------------------------
+@pytest.fixture(scope="module")
+def backend_compiles():
+    """A running count of XLA backend compilations (persistent-cache loads
+    included), as ``bench/compile_counter.py`` counts them. ``jax.monitoring``
+    keeps a listener for the life of the process, so one per module."""
+    import jax
+    seen = [0]
+
+    def count(event, duration_secs, **kwargs):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen[0] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(count)
+    return seen
+
+
+@pytest.mark.parametrize("attached", [False, True],
+                         ids=["rebuild", "incremental"])
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_unpadded_queue_lengths_compile_nothing_new(backend, attached,
+                                                    backend_compiles,
+                                                    monkeypatch):
+    """Once each padded queue length has been scored, queues of any real
+    length up to it compile no new program: the padding rows are dropped
+    after the readback, on the host. The (p, N) scores are the first p rows
+    of the padded device result bit for bit, in a contiguous array of their
+    own, and match the numpy reference."""
+    from repro.cluster.node import FleetState, make_fleet_nodes
+    from repro.core import scheduler as scheduler_mod
+    n = 100
+    fleet = FleetState.from_nodes(make_fleet_nodes(n, seed=3,
+                                                   utilization=0.4))
+    sched = BatchScheduler("energy_centric", backend=backend)
+    if attached:
+        sched.attach(fleet)
+    table = fleet if attached else NodeTable.from_nodes(fleet.nodes)
+    ref = BatchScheduler("energy_centric", backend="numpy")
+    kinds = list(WORKLOADS.values())
+    queue = lambda p: [Pod(i, kinds[i % len(kinds)], "topsis")
+                       for i in range(p)]
+    lengths = (1, 3, 37, 255, 257)
+    # every pod kind first, so that the kind cache holds them all while
+    # each padded length compiles
+    sched.score_queue(queue(len(kinds)), table)
+    for pad in sorted({scheduler_mod._pow2_pad_len(p) for p in lengths}):
+        sched.score_queue(queue(pad), table)
+    padded = []
+    readback = scheduler_mod._readback
+
+    def keep(tel, cc, labels, p=None):
+        # pod-minor (Fortran order), as the TPU hands the scores back
+        full = np.asfortranarray(np.asarray(cc))
+        padded.append(full)
+        return readback(tel, full, labels, p)
+
+    monkeypatch.setattr(scheduler_mod, "_readback", keep)
+    before = backend_compiles[0]
+    for p in lengths:
+        got = sched.score_queue(queue(p), table)
+        full = padded[-1]
+        assert full.shape == (scheduler_mod._pow2_pad_len(p), n)
+        assert got.shape == (p, n)
+        # rows of their own: the commit gathers and reduces them without
+        # striding across the padding
+        assert got.flags.c_contiguous or got.flags.f_contiguous
+        np.testing.assert_array_equal(got, full[:p])
+        assert got.tobytes() == full[:p].tobytes()
+        want = ref.score_queue(queue(p), NodeTable.from_nodes(fleet.nodes))
+        finite = np.isfinite(want)
+        np.testing.assert_array_equal(finite, np.isfinite(got))
+        np.testing.assert_allclose(got[finite], want[finite], atol=1e-5)
+    assert backend_compiles[0] == before
+    assert len(padded) == len(lengths)

@@ -209,3 +209,66 @@ def test_disabled_default_records_nothing_and_annotates_nothing(monkeypatch):
                                      device_trace=True)) as tel:
         _policy_free_jax_run()
     assert len(opened) == len(tel.spans) > 0
+
+
+def _pressure_run():
+    """A 20-node fleet whose idle nodes fall asleep between two bursts, so
+    that the second burst's unplaced pods wake nodes: one probe for a node
+    per pod that no node woken earlier in the pass covers."""
+    from repro.cluster.node import make_scenario_cluster
+    from repro.cluster.workload import WORKLOADS, ArrivalProcess, Pod
+    from repro.core.elastic import AutoscalePolicy
+
+    class TwoBursts(ArrivalProcess):
+        def events(self):
+            kinds = list(WORKLOADS.values())
+            return [(1.0, [Pod(i, kinds[i % 3], "topsis")
+                           for i in range(6)]),
+                    (400.0, [Pod(100 + i, WORKLOADS["complex"], "topsis")
+                             for i in range(24)])]
+
+    return run_scenario(TwoBursts(), "energy_centric",
+                        cluster_factory=lambda: make_scenario_cluster(
+                            "mixed", 20, seed=3),
+                        batch=True, batch_backend="jax",
+                        autoscale=AutoscalePolicy(idle_timeout_s=30.0,
+                                                  min_awake=2,
+                                                  wake_on_pressure=True))
+
+
+def test_wake_probes_are_spans_inside_their_pass(monkeypatch):
+    """Each probe of the pressure pass for a node to wake is one
+    ``policy_wake_probe`` span and one ``policy_wake_probes`` count, inside
+    the pass's ``policy_wake_pass`` span; without a registry nothing is
+    recorded and the run places the same."""
+    from repro.core import elastic
+    calls = []
+    best = elastic._best_node
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return best(*a, **kw)
+
+    monkeypatch.setattr(elastic, "_best_node", counted)
+    with telemetry.enabled(Telemetry(timelines=False)) as tel:
+        res = _pressure_run()
+    log = tel.spans
+    probes = [s for s in log if s["name"] == "policy_wake_probe"]
+    passes = [i for i, s in enumerate(log) if s["name"] == "policy_wake_pass"]
+    assert res.wakes > 0 and len(probes) >= res.wakes
+    assert tel.counter_value("policy_wake_probes",
+                             policy="AutoscaleScheduling") \
+        == len(probes) == len(calls)
+    for s in probes:
+        assert s["parent"] in passes
+    for i in passes:
+        parent = log[i]["parent"]
+        assert log[parent]["name"] == "engine_policies"
+        assert log[parent]["labels"]["hook"] == "round_end"
+
+    n_calls = len(calls)
+    assert telemetry.active() is telemetry.NULL
+    plain = _pressure_run()
+    assert _outcome(plain) == _outcome(res)
+    assert len(calls) == 2 * n_calls
+    assert not hasattr(telemetry.NULL, "spans")
