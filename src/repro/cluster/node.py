@@ -242,15 +242,14 @@ class FleetState(NodeTable):
         self.used_mem[i] = node.used_mem
         self._touch(i)
 
-    def set_power_states(self, states: "Sequence[str | None]") -> None:
-        """Sync the power-state column to ``states`` (one entry per node),
-        touching only nodes whose state actually changed — the elastic
-        fleet rewrites all N states every round, but a round typically
-        transitions a handful of nodes. State changes dirty the node
-        because the ``awake`` mask feeds the energy and carbon-rate
-        criteria columns."""
-        changed = [i for i, (old, new)
-                   in enumerate(zip(self.power_state, states)) if old != new]
+    def set_power_states(self, idx: Sequence[int],
+                         states: "Sequence[str | None]") -> None:
+        """Set the power state of each node ``idx`` lists to the matching
+        entry of ``states``, touching only nodes whose state actually
+        changed. State changes dirty the node because the ``awake`` mask
+        feeds the energy and carbon-rate criteria columns."""
+        changed = [(i, s) for i, s in zip(idx, states)
+                   if self.power_state[i] != s]
         if not changed:
             return
         if self._state_known is None:
@@ -258,8 +257,7 @@ class FleetState(NodeTable):
                 [s is not None for s in self.power_state])
             self._state_awake = np.asarray(
                 [s is not None and s != ASLEEP for s in self.power_state])
-        for i in changed:
-            s = states[i]
+        for i, s in changed:
             self.power_state[i] = s
             self.nodes[i].power_state = s
             self._state_known[i] = s is not None

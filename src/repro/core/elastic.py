@@ -66,7 +66,8 @@ IDLE = "idle"
 ASLEEP = "asleep"
 WAKING = "waking"
 POWER_STATES = (ACTIVE, IDLE, ASLEEP, WAKING)
-AWAKE_STATES = frozenset((ACTIVE, IDLE, WAKING))
+# ElasticFleet's state codes: each state's index in POWER_STATES
+_ACTIVE, _IDLE, _ASLEEP, _WAKING = range(len(POWER_STATES))
 
 # --- per-class wake/sleep profiles ------------------------------------------
 # A suspended node retains a wake-on-LAN residual draw (fraction of idle);
@@ -162,37 +163,42 @@ def _best_node(sched, pod, nodes, t, exclude):
 class ElasticFleet:
     """Per-node power-state machine driven by the event-driven engine.
 
-    Tracks, per node: the committed-task count, when the node last became
-    empty (``IDLE``), an optional drain-forced sleep instant, and an
-    in-flight wake (``WAKING`` until ``wake_ready``). States are *queried*
-    at a time ``t`` (sleep transitions are lazy, see module docstring); the
-    corresponding IDLE/ASLEEP/WAKING intervals are materialized into the
-    run's ``PowerTimeline`` state ledger exactly when a node leaves them
-    (or at :meth:`close`), so state-dependent idle power and wake-transition
-    energy are accounted without time-stepping.
+    Tracks, per node, as (N,) arrays: the committed-task count, when the
+    node last became empty (``IDLE``), an optional drain-forced sleep
+    instant, and an in-flight wake (``WAKING`` until ``wake_ready``); NaN
+    stands for "none". States are *queried* at a time ``t`` in one
+    vectorised pass (sleep transitions are lazy, see module docstring);
+    the corresponding IDLE/ASLEEP/WAKING intervals are materialized into
+    the run's ``PowerTimeline`` state ledger exactly when a node leaves
+    them (or at :meth:`close`), so state-dependent idle power and
+    wake-transition energy are accounted without time-stepping.
+
+    ``table`` is the engine's delta-maintained ``FleetState`` (its source
+    of truth), when there is one: :meth:`write_states` mirrors power-state
+    changes into its columns — marking transitioned nodes dirty, which is
+    what keeps the schedulers' incremental energy/carbon criteria (they
+    depend on the awake mask) in sync — and wake scoring runs against it
+    instead of re-flattening the Node list.
     """
 
     def __init__(self, nodes: Sequence, policy: AutoscalePolicy,
-                 timeline, t0: float = 0.0):
+                 timeline, t0: float = 0.0, table=None):
         self.nodes = nodes
         self.policy = policy
         self.timeline = timeline
-        # optional delta-maintained FleetState (the engine's source of
-        # truth): when attached, write_states mirrors power-state changes
-        # into its columns — marking transitioned nodes dirty, which is
-        # what keeps the schedulers' incremental energy/carbon criteria
-        # (they depend on the awake mask) in sync — and wake scoring runs
-        # against it instead of re-flattening the Node list
-        self.table = None
+        self.table = table
         n = len(nodes)
-        self._running = [0] * n
-        # when the node last became empty (None while ACTIVE or WAKING)
-        self._idle_since: list[float | None] = [t0] * n
+        self._running = np.zeros(n, dtype=np.int64)
+        # when the node last became empty (NaN while ACTIVE or WAKING)
+        self._idle_since = np.full(n, float(t0))
         # drain-forced sleep instant (skips the idle timeout)
-        self._sleep_at: list[float | None] = [None] * n
+        self._sleep_at = np.full(n, np.nan)
         # in-flight wake: request time and completion time
-        self._wake_started: list[float | None] = [None] * n
-        self._wake_ready: list[float | None] = [None] * n
+        self._wake_started = np.full(n, np.nan)
+        self._wake_ready = np.full(n, np.nan)
+        self._min_awake = np.arange(n) < policy.min_awake
+        # the state codes write_states last pushed to the nodes
+        self._written: np.ndarray | None = None
         self.wakes = 0
         self.sleeps = 0
         self.write_states(t0)
@@ -201,64 +207,87 @@ class ElasticFleet:
     def _sleep_due(self, i: int) -> float:
         """The instant node i falls (or fell) asleep, given its current
         idle stretch; inf when it cannot auto-sleep."""
-        since = self._idle_since[i]
-        if since is None:
+        since = float(self._idle_since[i])
+        if math.isnan(since):
             return math.inf
-        if self._sleep_at[i] is not None:
-            return self._sleep_at[i]
-        if i < self.policy.min_awake:
+        sleep_at = float(self._sleep_at[i])
+        if not math.isnan(sleep_at):
+            return sleep_at
+        if self._min_awake[i]:
             return math.inf
         return since + self.policy.idle_timeout_s
 
-    def state(self, i: int, t: float) -> str:
-        if self._wake_ready[i] is not None:
-            return WAKING            # advance_to() clears completed wakes
-        if self._running[i] > 0:
-            return ACTIVE
-        return ASLEEP if t >= self._sleep_due(i) else IDLE
+    def codes(self, t: float) -> np.ndarray:
+        """(N,) power-state codes at ``t`` (indices into ``POWER_STATES``):
+        WAKING while a wake is in flight, else ACTIVE with tasks committed,
+        else ASLEEP from the sleep-due instant on, else IDLE."""
+        since = self._idle_since
+        forced = ~np.isnan(self._sleep_at)
+        due = np.where(forced, self._sleep_at,
+                       np.where(self._min_awake, np.inf,
+                                since + self.policy.idle_timeout_s))
+        due[np.isnan(since)] = np.inf
+        codes = np.where(t >= due, _ASLEEP, _IDLE)
+        codes[self._running > 0] = _ACTIVE
+        codes[~np.isnan(self._wake_ready)] = _WAKING
+        return codes
 
     def states(self, t: float) -> list[str]:
-        return [self.state(i, t) for i in range(len(self.nodes))]
+        return [POWER_STATES[c] for c in self.codes(t).tolist()]
 
-    def write_states(self, t: float) -> list[str]:
-        """Refresh every ``Node.power_state`` (the column the
-        awake/marginal-idle criterion derives from); with an attached
-        :attr:`table` the FleetState column is synced too, dirtying exactly
-        the nodes that transitioned."""
-        sts = self.states(t)
-        for node, s in zip(self.nodes, sts):
-            node.power_state = s
+    def write_states(self, t: float) -> np.ndarray:
+        """Push the states at ``t`` to ``Node.power_state`` (the column the
+        awake/marginal-idle criterion derives from) and to an attached
+        :attr:`table`, for the nodes whose state changed since the last
+        push (every node on the first), dirtying exactly those. Returns
+        the codes; a live registry counts the pushed transitions as
+        ``policy_state_transitions``."""
+        codes = self.codes(t)
+        first = self._written is None
+        changed = (np.arange(len(codes)) if first
+                   else np.flatnonzero(codes != self._written))
+        self._written = codes
+        if not changed.size:
+            return codes
+        idx = changed.tolist()
+        sts = [POWER_STATES[c] for c in codes[changed].tolist()]
+        for i, s in zip(idx, sts):
+            self.nodes[i].power_state = s
         if self.table is not None:
-            self.table.set_power_states(sts)
-        return sts
+            self.table.set_power_states(idx, sts)
+        if not first:
+            telemetry.active().inc("policy_state_transitions",
+                                   value=float(len(idx)),
+                                   policy="AutoscaleScheduling")
+        return codes
 
     def exclude_mask(self, t: float) -> np.ndarray:
         """(N,) bool: nodes no scheduler may place on this round (ASLEEP —
         capacity comes back only through a policy wake)."""
-        return np.asarray([s == ASLEEP for s in self.states(t)])
+        return self.codes(t) == _ASLEEP
 
     def exclude_for_deadline(self, base: np.ndarray,
                              deadline: float) -> np.ndarray:
         """``base`` plus WAKING nodes whose wake completes after
         ``deadline`` — a deferrable pod must never be started past it, and
         a pod committed to a WAKING node starts at its ready time."""
-        ready = np.asarray([-math.inf if r is None else r
-                            for r in self._wake_ready])
-        return base | (ready > deadline)
+        return base | (self._wake_ready > deadline)
 
     def next_transition(self, t: float) -> float | None:
         """Earliest in-flight wake completion strictly after ``t`` (the only
         state transition needing an engine event — sleeps are lazy and
         change no scheduling outcome until a round queries them)."""
-        cands = [r for r in self._wake_ready if r is not None and r > t]
-        return min(cands) if cands else None
+        later = self._wake_ready > t
+        if not later.any():
+            return None
+        return float(self._wake_ready[later].min())
 
     # --- ledger materialization ----------------------------------------------
     def _materialize_idle(self, i: int, upto: float) -> None:
         """Flush node i's open idle stretch (and the ASLEEP tail it lazily
         decayed into) to the state ledger, up to ``upto``."""
-        since = self._idle_since[i]
-        if since is None:
+        since = float(self._idle_since[i])
+        if math.isnan(since):
             return
         node = self.nodes[i]
         due = self._sleep_due(i)
@@ -272,33 +301,39 @@ class ElasticFleet:
             self.sleeps += 1
             telemetry.active().inc("policy_node_sleeps",
                                    policy="AutoscaleScheduling")
-        self._idle_since[i] = None
-        self._sleep_at[i] = None
+        self._idle_since[i] = np.nan
+        self._sleep_at[i] = np.nan
+
+    def _finish_wake(self, i: int, end: float) -> float:
+        """Land node i's WAKING interval, up to ``end``, in the ledger and
+        clear the wake; returns its ready instant."""
+        node = self.nodes[i]
+        ready = float(self._wake_ready[i])
+        self.timeline.add_state(
+            node.name, node.node_class, WAKING,
+            float(self._wake_started[i]), end,
+            NODE_ENERGY_PROFILES[node.node_class]["idle_power"])
+        self._wake_started[i] = np.nan
+        self._wake_ready[i] = np.nan
+        return ready
 
     def advance_to(self, t: float) -> None:
         """Finalize wake transitions completed by ``t`` (called whenever the
         engine's clock advances): the WAKING interval lands in the ledger
         and the node becomes ACTIVE (tasks were committed while it woke) or
         IDLE."""
-        for i, ready in enumerate(self._wake_ready):
-            if ready is None or ready > t:
-                continue
-            node = self.nodes[i]
-            self.timeline.add_state(
-                node.name, node.node_class, WAKING,
-                self._wake_started[i], ready,
-                NODE_ENERGY_PROFILES[node.node_class]["idle_power"])
-            self._wake_started[i] = None
-            self._wake_ready[i] = None
-            self._idle_since[i] = ready if self._running[i] == 0 else None
+        for i in np.flatnonzero(self._wake_ready <= t).tolist():
+            ready = self._finish_wake(i, float(self._wake_ready[i]))
+            self._idle_since[i] = ready if self._running[i] == 0 else np.nan
 
     # --- engine hooks --------------------------------------------------------
     def on_commit(self, i: int, t: float) -> float:
         """Resources bound on node i at clock ``t``; returns the task's
         effective start — ``t``, or the wake-completion instant when the
         node is still WAKING."""
-        if self._wake_ready[i] is not None:
-            start = self._wake_ready[i]
+        ready = float(self._wake_ready[i])
+        if not math.isnan(ready):
+            start = ready
         else:
             if t >= self._sleep_due(i):
                 raise RuntimeError(
@@ -307,12 +342,12 @@ class ElasticFleet:
             start = t
             self._materialize_idle(i, t)
         self._running[i] += 1
-        self._idle_since[i] = None
+        self._idle_since[i] = np.nan
         return start
 
     def on_complete(self, i: int, end_t: float) -> None:
         self._running[i] -= 1
-        if self._running[i] == 0 and self._wake_ready[i] is None:
+        if self._running[i] == 0 and math.isnan(self._wake_ready[i]):
             self._idle_since[i] = end_t
 
     def on_evict(self, i: int, t: float) -> None:
@@ -325,14 +360,15 @@ class ElasticFleet:
         node = self.nodes[i]
         self._materialize_idle(i, t)
         prof = NODE_WAKE_PROFILES[node.node_class]
+        ready = t + prof["wake_latency_s"]
         self._wake_started[i] = t
-        self._wake_ready[i] = t + prof["wake_latency_s"]
+        self._wake_ready[i] = ready
         self.timeline.add_wake(node.name, node.node_class, t,
                                prof["wake_energy_j"])
         self.wakes += 1
         telemetry.active().inc("policy_node_wakes",
                                policy="AutoscaleScheduling")
-        return self._wake_ready[i]
+        return ready
 
     def force_sleep(self, i: int, t: float) -> None:
         """Drain completed: the (now empty) node sleeps immediately,
@@ -342,20 +378,15 @@ class ElasticFleet:
 
     def close(self, horizon: float) -> None:
         """End of run: flush every open state interval up to ``horizon``."""
-        for i, node in enumerate(self.nodes):
-            ready = self._wake_ready[i]
-            if ready is not None:
+        for i in range(len(self.nodes)):
+            ready = float(self._wake_ready[i])
+            if not math.isnan(ready):
                 # a wake still in flight (pressure-woken, pods landed
                 # elsewhere): charge the transition up to the horizon
-                self.timeline.add_state(
-                    node.name, node.node_class, WAKING,
-                    self._wake_started[i], min(ready, horizon),
-                    NODE_ENERGY_PROFILES[node.node_class]["idle_power"])
-                self._wake_started[i] = None
-                self._wake_ready[i] = None
+                self._finish_wake(i, min(ready, horizon))
                 if ready < horizon and self._running[i] == 0:
                     self._idle_since[i] = ready
-                    self._sleep_at[i] = None
+                    self._sleep_at[i] = np.nan
                     self._materialize_idle(i, horizon)
                 continue
             self._materialize_idle(i, horizon)
@@ -373,7 +404,7 @@ class ElasticFleet:
             return []
         tel = telemetry.active()
         with tel.stage("policy_wake_pass"):
-            asleep = np.asarray([s == ASLEEP for s in self.states(t)])
+            asleep = self.exclude_mask(t)
             if not asleep.any():
                 return []
             woken: list[int] = []
@@ -427,22 +458,24 @@ class ElasticFleet:
         pressure wake recovers the capacity). ``running`` holds the
         kernel's ``RunningTask`` entries; returns (drained node indices,
         victim entries)."""
-        sts = self.states(t)
+        codes = self.codes(t)
         by_node: dict[int, list] = {}
         for e in running:
             by_node.setdefault(e.node_index, []).append(e)
+        active = codes == _ACTIVE
         cands = sorted(
             (i for i in by_node
-             if sts[i] == ACTIVE and i >= self.policy.min_awake
+             if active[i] and i >= self.policy.min_awake
              and self.nodes[i].cpu_util < self.policy.consolidate_util_below),
             key=lambda i: (self.nodes[i].cpu_util, i))
         if not cands:
             return [], []
-        n_awake = sum(s in AWAKE_STATES for s in sts)
+        n_awake = int(np.count_nonzero(codes != _ASLEEP))
         # conservative ledger: candidates host nobody else's victims
+        drainable = set(cands)
         base = {i: (self.nodes[i].free_cpu, self.nodes[i].free_mem)
-                for i, s in enumerate(sts)
-                if s in (ACTIVE, IDLE) and i not in set(cands)}
+                for i in np.flatnonzero(active | (codes == _IDLE)).tolist()
+                if i not in drainable}
         ledger = {i: list(cap) for i, cap in base.items()}
         drained: list[int] = []
         victims: list = []
@@ -526,22 +559,22 @@ class AutoscaleScheduling(SchedulingPolicy):
         self.next_consolidate = policy.consolidate_interval_s
 
     def bind(self, sim) -> None:
-        self.fleet = ElasticFleet(sim.state.nodes, self.policy,
-                                  sim.state.timeline)
         # adopt the engine's FleetState so power-state transitions land in
         # its columns (dirty-tracked) the moment write_states runs
-        self.fleet.table = getattr(sim.state, "fleet", None)
+        self.fleet = ElasticFleet(sim.state.nodes, self.policy,
+                                  sim.state.timeline,
+                                  table=getattr(sim.state, "fleet", None))
 
     def on_clock(self, sim, t: float) -> None:
         self.fleet.advance_to(t)
         tel = telemetry.active()
         if tel.timelines:
-            # observer-only: per-state node counts over sim time (states()
+            # observer-only: per-state node counts over sim time (codes()
             # is a read-only view, so recording can't perturb the run)
-            states = self.fleet.states(t)
-            for state in POWER_STATES:
-                tel.record("fleet_state_nodes", t,
-                           float(states.count(state)), state=state)
+            counts = np.bincount(self.fleet.codes(t),
+                                 minlength=len(POWER_STATES))
+            for state, count in zip(POWER_STATES, counts.tolist()):
+                tel.record("fleet_state_nodes", t, float(count), state=state)
 
     def on_round_start(self, sim, t: float) -> None:
         if self.next_consolidate is None or t < self.next_consolidate:
@@ -566,8 +599,7 @@ class AutoscaleScheduling(SchedulingPolicy):
         self.next_consolidate = t + self.policy.consolidate_interval_s
 
     def exclude_mask(self, sim, t: float) -> np.ndarray:
-        self.fleet.write_states(t)
-        return self.fleet.exclude_mask(t)
+        return self.fleet.write_states(t) == _ASLEEP
 
     def exclude_for(self, sim, pod, base: np.ndarray,
                     t: float) -> np.ndarray | None:

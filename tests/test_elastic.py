@@ -39,6 +39,7 @@ except ModuleNotFoundError:
 
     st = _AnyStrategy()
 
+from repro.core import telemetry
 from repro.core.carbon import CarbonPolicy, ConstantCarbon, TraceCarbon
 from repro.core.elastic import (ACTIVE, ASLEEP, IDLE, WAKING,
                                 AutoscalePolicy, ElasticFleet,
@@ -46,8 +47,10 @@ from repro.core.elastic import (ACTIVE, ASLEEP, IDLE, WAKING,
 from repro.core.energy import NODE_ENERGY_PROFILES, PowerTimeline
 from repro.core.scheduler import (BatchScheduler, DefaultK8sScheduler,
                                   GreenPodScheduler)
+from repro.core.telemetry import Telemetry
 from repro.cluster.engine import RunningTask
-from repro.cluster.node import Node, NodeTable, make_scenario_cluster
+from repro.cluster.node import (FleetState, Node, NodeTable,
+                                make_scenario_cluster)
 from repro.cluster.simulator import run_scenario, table6
 from repro.cluster.workload import (WORKLOADS, Pod, PoissonArrivals,
                                     TraceArrivals)
@@ -164,8 +167,10 @@ def test_property_disabled_policy_is_bitwise_inert(seed, profile):
         assert not res.timeline.wake_transitions
         assert res.wakes == res.sleeps == res.migrations == 0
         # with the ledger empty the fleet totals reduce to the legacy ones
-        assert res.fleet_idle_energy_kj() * 1000.0 \
-            == res.timeline.idle_energy_j(None)
+        # (compared in kJ, the unit the total is computed in: J / 1000 *
+        # 1000 need not give the same double back)
+        assert res.fleet_idle_energy_kj() \
+            == res.timeline.idle_energy_j(None) / 1000.0
 
 
 def test_table6_still_matches_golden_bitwise():
@@ -460,6 +465,254 @@ def test_waking_node_excluded_for_deadline_late_deferrable_pod():
     late = fleet.exclude_for_deadline(base, deadline=101.0)
     ok = fleet.exclude_for_deadline(base, deadline=102.0)   # ready == ddl
     assert late[0] and not ok[0]
+
+
+# --- the state machine against a per-node reference -------------------------
+class _PerNodeFleet:
+    """The power-state rules node by node, on plain lists: the reference
+    ``ElasticFleet``'s vectorised state pass is held to."""
+
+    def __init__(self, nodes, policy, timeline):
+        self.nodes, self.policy, self.timeline = nodes, policy, timeline
+        n = len(nodes)
+        self.running = [0] * n
+        self.idle_since = [0.0] * n
+        self.sleep_at = [None] * n
+        self.wake_started = [None] * n
+        self.wake_ready = [None] * n
+
+    def sleep_due(self, i):
+        if self.idle_since[i] is None:
+            return math.inf
+        if self.sleep_at[i] is not None:
+            return self.sleep_at[i]
+        if i < self.policy.min_awake:
+            return math.inf
+        return self.idle_since[i] + self.policy.idle_timeout_s
+
+    def state(self, i, t):
+        if self.wake_ready[i] is not None:
+            return WAKING
+        if self.running[i] > 0:
+            return ACTIVE
+        return ASLEEP if t >= self.sleep_due(i) else IDLE
+
+    def states(self, t):
+        return [self.state(i, t) for i in range(len(self.nodes))]
+
+    def next_transition(self, t):
+        later = [r for r in self.wake_ready if r is not None and r > t]
+        return min(later) if later else None
+
+    def exclude_for_deadline(self, base, deadline):
+        return [b or (r is not None and r > deadline)
+                for b, r in zip(base, self.wake_ready)]
+
+    def flush_idle(self, i, upto):
+        since = self.idle_since[i]
+        if since is None:
+            return
+        node, due = self.nodes[i], self.sleep_due(i)
+        self.timeline.add_state(
+            node.name, node.node_class, IDLE, since, min(upto, due),
+            NODE_ENERGY_PROFILES[node.node_class]["idle_power"])
+        if upto > due:
+            self.timeline.add_state(
+                node.name, node.node_class, ASLEEP, max(due, since), upto,
+                NODE_WAKE_PROFILES[node.node_class]["sleep_power_w"])
+        self.idle_since[i] = self.sleep_at[i] = None
+
+    def flush_wake(self, i, end):
+        node = self.nodes[i]
+        self.timeline.add_state(
+            node.name, node.node_class, WAKING, self.wake_started[i], end,
+            NODE_ENERGY_PROFILES[node.node_class]["idle_power"])
+        self.wake_started[i] = self.wake_ready[i] = None
+
+    def advance_to(self, t):
+        for i, ready in enumerate(self.wake_ready):
+            if ready is not None and ready <= t:
+                self.flush_wake(i, ready)
+                self.idle_since[i] = ready if self.running[i] == 0 else None
+
+    def on_commit(self, i, t):
+        start = self.wake_ready[i]
+        if start is None:
+            start = t
+            self.flush_idle(i, t)
+        self.running[i] += 1
+        self.idle_since[i] = None
+        return start
+
+    def on_complete(self, i, t):
+        self.running[i] -= 1
+        if self.running[i] == 0 and self.wake_ready[i] is None:
+            self.idle_since[i] = t
+
+    def request_wake(self, i, t):
+        node = self.nodes[i]
+        self.flush_idle(i, t)
+        prof = NODE_WAKE_PROFILES[node.node_class]
+        self.wake_started[i] = t
+        self.wake_ready[i] = t + prof["wake_latency_s"]
+        self.timeline.add_wake(node.name, node.node_class, t,
+                               prof["wake_energy_j"])
+        return self.wake_ready[i]
+
+    def force_sleep(self, i, t):
+        self.idle_since[i] = self.sleep_at[i] = t
+
+    def close(self, horizon):
+        for i in range(len(self.nodes)):
+            ready = self.wake_ready[i]
+            if ready is not None:
+                self.flush_wake(i, min(ready, horizon))
+                if ready < horizon and self.running[i] == 0:
+                    self.idle_since[i], self.sleep_at[i] = ready, None
+                    self.flush_idle(i, horizon)
+                continue
+            self.flush_idle(i, horizon)
+
+
+def _next_clock(rng, t, ref):
+    """A later clock instant: a random step, or exactly a wake's ready
+    instant or an idle node's sleep-due instant (the boundaries)."""
+    due = [d for d in (ref.sleep_due(i) for i in range(len(ref.nodes)))
+           if t < d < math.inf]
+    ready = ref.next_transition(t)
+    pick = rng.integers(4)
+    if pick == 0 and due:
+        return float(due[int(rng.integers(len(due)))])
+    if pick == 1 and ready is not None:
+        return ready
+    return t + float(rng.exponential(12.0))
+
+
+@pytest.mark.parametrize("idle_timeout_s", [30.0, math.inf])
+@pytest.mark.parametrize("min_awake", [0, 3])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_state_machine_matches_per_node_reference(seed, min_awake,
+                                                  idle_timeout_s):
+    """A seeded random run of commits, completions, evictions, wakes,
+    drains and clock advances on 64 nodes: after every step the states,
+    the exclude mask, the next transition and the deadline masks equal a
+    per-node reference of the rules, each sync dirties exactly the nodes
+    whose state changed, and the state ledgers agree to the bit."""
+    rng = np.random.default_rng(seed)
+    nodes = make_scenario_cluster("mixed", 64, seed=seed)
+    policy = AutoscalePolicy(idle_timeout_s=idle_timeout_s,
+                             min_awake=min_awake)
+    table = FleetState.from_nodes(nodes)
+    fleet = ElasticFleet(nodes, policy, PowerTimeline(), table=table)
+    ref = _PerNodeFleet(nodes, policy, PowerTimeline())
+    t = 0.0
+    synced = ref.states(t)
+    assert fleet.states(t) == synced == list(table.power_state)
+    for _ in range(400):
+        op, i = int(rng.integers(6)), int(rng.integers(len(nodes)))
+        sts = ref.states(t)
+        if op == 0 and sts[i] != ASLEEP:
+            assert fleet.on_commit(i, t) == ref.on_commit(i, t)
+        elif op == 1 and ref.running[i] > 0:
+            fleet.on_complete(i, t)
+            ref.on_complete(i, t)
+        elif op == 2 and ref.running[i] > 0:
+            fleet.on_evict(i, t)
+            ref.on_complete(i, t)
+        elif op == 3 and sts[i] == ASLEEP:
+            assert fleet.request_wake(i, t) == ref.request_wake(i, t)
+        elif op == 4 and sts[i] == ACTIVE:
+            while ref.running[i]:              # drain, then sleep at once
+                fleet.on_evict(i, t)
+                ref.on_complete(i, t)
+            fleet.force_sleep(i, t)
+            ref.force_sleep(i, t)
+        else:
+            t = _next_clock(rng, t, ref)
+            fleet.advance_to(t)
+            ref.advance_to(t)
+        version = table.version
+        fleet.write_states(t)
+        want = ref.states(t)
+        assert table.modified_since(version).tolist() == [
+            j for j, (a, b) in enumerate(zip(synced, want)) if a != b]
+        synced = want
+        assert fleet.states(t) == want == list(table.power_state)
+        assert [n.power_state for n in nodes] == want
+        base = fleet.exclude_mask(t)
+        assert base.tolist() == [s == ASLEEP for s in want]
+        assert fleet.next_transition(t) == ref.next_transition(t)
+        for deadline in (t, t + 1.0, t + 4.0, t + 8.0):
+            assert fleet.exclude_for_deadline(base, deadline).tolist() == \
+                ref.exclude_for_deadline(base.tolist(), deadline)
+    fleet.close(t + 100.0)
+    ref.close(t + 100.0)
+    assert fleet.timeline.state_intervals == ref.timeline.state_intervals
+    assert fleet.timeline.wake_transitions == ref.timeline.wake_transitions
+
+
+def test_sync_pushes_only_the_nodes_that_changed():
+    """A sync with no transition dirties nothing; with a live registry
+    ``policy_state_transitions`` counts exactly the nodes a sync pushed,
+    and without one nothing is counted."""
+    nodes = [Node(f"a-{i}", "A", 4, 16) for i in range(8)]
+    table = FleetState.from_nodes(nodes)
+    fleet = ElasticFleet(nodes, AutoscalePolicy(idle_timeout_s=30.0,
+                                                min_awake=0),
+                         PowerTimeline(), table=table)
+    fleet.on_commit(1, 0.0)
+    fleet.on_commit(2, 0.0)
+    with telemetry.enabled(Telemetry(timelines=False)) as tel:
+        version = table.version
+        fleet.write_states(5.0)                  # nodes 1 and 2 go ACTIVE
+        assert table.modified_since(version).tolist() == [1, 2]
+        version = table.version
+        fleet.write_states(6.0)                  # nothing changes
+        assert table.version == version
+        fleet.write_states(30.0)                 # six idle nodes sleep
+        assert table.modified_since(version).tolist() == [0, 3, 4, 5, 6, 7]
+        assert tel.counter_value("policy_state_transitions",
+                                 policy="AutoscaleScheduling") == 8.0
+    fleet.on_complete(1, 31.0)
+    fleet.write_states(31.0)
+    assert telemetry.active() is telemetry.NULL
+    assert nodes[1].power_state == IDLE == table.power_state[1]
+
+
+def test_values_handed_to_the_state_ledger_are_python_floats(monkeypatch):
+    """Every time and power ``ElasticFleet`` posts to ``PowerTimeline`` is a
+    Python ``float``, never a numpy scalar read off its arrays."""
+    posted = []
+    add_state, add_wake = PowerTimeline.add_state, PowerTimeline.add_wake
+
+    def state(tl, node, cls, st, start_s, end_s, power_w):
+        posted.append((st, start_s, end_s, power_w))
+        return add_state(tl, node, cls, st, start_s, end_s, power_w)
+
+    def wake(tl, node, cls, t_s, energy_j):
+        posted.append((WAKING, t_s, energy_j))
+        return add_wake(tl, node, cls, t_s, energy_j)
+
+    monkeypatch.setattr(PowerTimeline, "add_state", state)
+    monkeypatch.setattr(PowerTimeline, "add_wake", wake)
+    res = run_scenario(
+        PoissonArrivals(rate_per_s=0.05, n_bursts=6, burst_size=6, seed=4,
+                        deferrable_share=0.5, deadline_s=400.0),
+        "carbon_energy_balanced",
+        cluster_factory=lambda: make_scenario_cluster("mixed", 12, seed=4),
+        batch=True, batch_backend="numpy",
+        carbon=CarbonPolicy(TraceCarbon([{"t": 0.0, "intensity": 100.0},
+                                         {"t": 40.0, "intensity": 500.0},
+                                         {"t": 90.0, "intensity": 100.0}]),
+                            defer_threshold=300.0, preempt_threshold=400.0,
+                            check_interval_s=15.0),
+        autoscale=AutoscalePolicy(idle_timeout_s=20.0, min_awake=1,
+                                  consolidate_interval_s=30.0,
+                                  consolidate_util_below=0.3))
+    assert res.wakes > 0 and res.sleeps > 0
+    assert {p[0] for p in posted} >= {IDLE, ASLEEP, WAKING}
+    for entry in posted:
+        assert all(type(v) is float for v in entry[1:]), entry
 
 
 # --- state-ledger accounting -------------------------------------------------

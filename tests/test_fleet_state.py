@@ -78,13 +78,9 @@ def _apply(fs: FleetState, ops, bound=None) -> None:
         elif kind == "evict" and bound:
             fs.release(*bound.pop(0))
         elif kind == "sleep":
-            states = list(fs.power_state)
-            states[i] = ASLEEP
-            fs.set_power_states(states)
+            fs.set_power_states([i], [ASLEEP])
         elif kind == "wake":
-            states = list(fs.power_state)
-            states[i] = IDLE
-            fs.set_power_states(states)
+            fs.set_power_states([i], [IDLE])
 
 
 def _queue(n_pods: int = 6) -> list[Pod]:
@@ -147,7 +143,7 @@ def test_modified_since_is_a_multi_consumer_cursor():
     assert fs.modified_since(fs.version).size == 0
     # a no-op power-state write must not dirty anything
     v2 = fs.version
-    fs.set_power_states(list(fs.power_state))
+    fs.set_power_states(range(len(fs)), list(fs.power_state))
     assert fs.version == v2
 
 
