@@ -9,9 +9,10 @@ device-resident, no re-upload per scheme):
 
   fused   — ONE ``BatchScheduler.score_queue_grid`` call over the whole
             (S, C) grid: one engine dispatch for the (S, P, N) tensor
-            (jax: ``topsis.closeness_grid``; pallas: the weight-grid kernel
-            with schemes innermost so each criteria node-block is fetched
-            once; numpy: the scheme x pod reference loop)
+            (jax: one gather + vmapped closeness; pallas: the
+            weight-grid kernel with schemes innermost so each criteria
+            node-block is fetched once; numpy: the scheme x pod reference
+            loop)
   serial  — S single-scheme ``score_queue_grid`` calls, one per grid row:
             the pre-grid status quo of one scoring round per scheme. On
             numpy both modes are the same Python loop (speedup ~1x, there

@@ -8,23 +8,24 @@ ways:
                  (numpy backend: the latency path, one rescore per bind;
                  only timed through N=8192 — it is off the pareto front
                  long before that)
-  rebuild      — the pre-FleetState round: flatten the Node list into a
-                 fresh NodeTable snapshot, build the (P, N, C) decision
-                 tensor from scratch, score (BatchScheduler's full-rebuild
-                 path, kept as the reference oracle)
-  incremental  — the delta-maintained round: an attached FleetState with
-                 dirty-column sync (FleetCriteriaCache), scoring through
-                 the per-kind (K, N, C) cache — numpy reads zero-copy row
-                 views, jax gathers from the device-resident donated
-                 mirror in one dispatch, pallas streams kind blocks
+  rebuild      — numpy only, the pre-FleetState round: flatten the Node
+                 list into a fresh NodeTable snapshot, build the (P, N, C)
+                 decision tensor from scratch, score (BatchScheduler's
+                 full-rebuild path, kept as the reference oracle)
+  incremental  — every backend, the delta-maintained round: an attached
+                 FleetState with dirty-column sync (FleetCriteriaCache),
+                 scoring through the per-kind (K, N, C) cache — numpy reads
+                 zero-copy row views, jax gathers from the device-resident
+                 donated mirror in one dispatch, pallas streams kind blocks
                  through the scalar-prefetch kernel
 
 Each timed rep first touches ~32 random node columns (bind+release pairs:
 net-zero capacity, but they dirty the columns) so the incremental path
-pays its per-round delta sync honestly. Every backend/mode closeness
-matrix is asserted against ``topsis.closeness_np`` within 1e-5 before
-timing. The pallas backend runs the kernel in interpret mode off-TPU
-(recorded as ``interpret_mode``) and is capped at ``--pallas-max-nodes``
+pays its per-round delta sync honestly; ``speedup_vs_rebuild`` divides
+the numpy rebuild's time by it. Every backend/mode closeness matrix is
+asserted against the numpy rebuild within 1e-5 before timing. The
+pallas backend runs the kernel in interpret mode off-TPU (recorded as
+``interpret_mode``) and is capped at ``--pallas-max-nodes``
 (default 8192) there — interpret-mode wall time is not a kernel
 measurement, the cap just keeps the sweep finishable on CPU. Results are
 printed as CSV and written to BENCH_scheduling.json.
@@ -124,31 +125,24 @@ def run(backends=BACKENDS, node_counts=DEFAULT_NODES, n_pods: int = 64,
             emit({"mode": "per-pod", "backend": "numpy", "n_nodes": n,
                   "pods": n_pods, "ms_total": t * 1e3,
                   "us_per_pod": t / n_pods * 1e6})
-        want = BatchScheduler("energy_centric", backend="numpy").score_queue(
-            pods, NodeTable.from_nodes(fleet.nodes))
+        # rebuild: flatten + full (P, N, C) build + score, per round —
+        # the numpy reference every backend is verified against
+        s_reb = BatchScheduler("energy_centric", backend="numpy")
+        want = s_reb.score_queue(pods, NodeTable.from_nodes(fleet.nodes))
+        t_reb = _time(
+            lambda: (_dirty(fleet, rng),
+                     s_reb.select_many(pods,
+                                       NodeTable.from_nodes(fleet.nodes))),
+            reps=n_reps)
+        emit({"mode": "rebuild", "backend": "numpy", "n_nodes": n,
+              "pods": n_pods, "ms_total": t_reb * 1e3,
+              "us_per_pod": t_reb / n_pods * 1e6})
         for backend in backends:
             if backend == "pallas" and interpret_mode \
                     and n > pallas_max_nodes:
                 print(f"# skip pallas at N={n}: interpret mode "
                       f"(--pallas-max-nodes {pallas_max_nodes})")
                 continue
-            # rebuild: flatten + full (P, N, C) build + score, per round
-            s_reb = BatchScheduler("energy_centric", backend=backend)
-            verify_scores(
-                f"rebuild/{backend}/N={n}",
-                s_reb.score_queue(pods, NodeTable.from_nodes(fleet.nodes)),
-                want)
-            t_reb = _time(
-                lambda: (_dirty(fleet, rng),
-                         s_reb.select_many(
-                             pods, NodeTable.from_nodes(fleet.nodes))),
-                reps=n_reps)
-            rec = {"mode": "rebuild", "backend": backend, "n_nodes": n,
-                   "pods": n_pods, "ms_total": t_reb * 1e3,
-                   "us_per_pod": t_reb / n_pods * 1e6}
-            if backend == "pallas":
-                rec["interpret_mode"] = interpret_mode
-            emit(rec)
             # incremental: attached FleetState, dirty-column sync only
             s_inc = BatchScheduler("energy_centric", backend=backend)
             s_inc.attach(fleet)

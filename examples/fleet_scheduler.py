@@ -54,28 +54,47 @@ print(f"straggler alert -> degraded {slices[cur].name} (health "
 # --- fleet-scale batched scheduling ---------------------------------------------
 # The paper's cluster has 4 nodes; the batched engine scores a whole queue
 # of pods against thousands of candidate nodes in one TOPSIS pass
-# (BatchScheduler.select_many — numpy for reference, jax/pallas for
-# throughput; see benchmarks/scheduling_time.py for the full sweep).
+# (BatchScheduler.select_many). numpy rebuilds the decision tensor from a
+# snapshot as the reference; jax scores the fleet it is attached to
+# through the per-kind criteria cache, gathered on the device (see
+# benchmarks/scheduling_time.py for the full sweep).
 import time
 
+import numpy as np
+
 from repro.core.scheduler import BatchScheduler
-from repro.cluster.node import make_fleet
+from repro.cluster.node import FleetState, NodeTable, make_fleet_nodes
 from repro.cluster.workload import WORKLOADS, Pod
 
 N_NODES, N_PODS = 2048, 64
-table = make_fleet(N_NODES, seed=0, utilization=0.3)
+fleet = FleetState.from_nodes(make_fleet_nodes(N_NODES, seed=0,
+                                               utilization=0.3))
 queue = [Pod(i, WORKLOADS[("light", "medium", "complex")[i % 3]], "topsis")
          for i in range(N_PODS)]
 print(f"\n--- batched fleet scheduling: {N_PODS} pods x {N_NODES} nodes")
+reference = None
 for backend in ("numpy", "jax"):
     sched = BatchScheduler("energy_centric", backend=backend)
+    if backend == "numpy":
+        table = NodeTable.from_nodes(fleet.nodes)   # rebuilt snapshot
+    else:
+        sched.attach(fleet)
+        table = fleet
     sched.select_many(queue, table)            # warm up (jit compile)
     t0 = time.perf_counter()
     assignments, diag = sched.select_many(queue, table)
     dt = time.perf_counter() - t0
     placed = sum(a is not None for a in assignments)
+    cc = diag["closeness"]
+    if reference is None:
+        reference = cc
+    finite = np.isfinite(reference)
+    assert np.array_equal(finite, np.isfinite(cc))
+    err = float(np.max(np.abs(cc[finite] - reference[finite])))
+    assert err < 1e-5, f"{backend}: closeness err {err:.2e} vs numpy"
     print(f"  {backend:6s}: {placed}/{N_PODS} placed in {dt * 1e3:7.2f} ms "
-          f"({diag['per_pod_time_s'] * 1e6:.0f} us/pod)")
+          f"({diag['per_pod_time_s'] * 1e6:.0f} us/pod, "
+          f"max closeness err vs numpy {err:.1e})")
 
 # --- event-driven scenario: Poisson bursts, time-resolved energy ----------------
 # Beyond the one-shot queue above: stream Poisson arrival bursts onto an

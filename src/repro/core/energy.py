@@ -28,6 +28,20 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
+import operator
+
+
+def ledger_sum(values) -> float:
+    """Add ``values`` left to right, in the order the iterable yields them.
+
+    Every total of the energy ledger goes through this fold. Since Python
+    3.12 the built-in ``sum()`` adds floats with compensated (Neumaier)
+    summation (CPython gh-100425), so its last bit depends on the
+    interpreter. The engine's totals are defined as the plain commit-order
+    fold instead: the same bits on every interpreter, and the values the
+    recorded goldens hold. Like ``sum()`` it starts from the integer 0."""
+    return functools.reduce(operator.add, values, 0)
 
 
 def blade_power(u_cpu_pct: float, u_mem_acc_per_s: float = 0.0,
@@ -138,7 +152,7 @@ def union_length(intervals: list[tuple[float, float]]) -> float:
     """Total length of the union of [start, end) intervals (same merge
     order and summation order as the legacy simulator ``_union_length``,
     so totals agree bitwise)."""
-    return sum(e - s for s, e in merge_intervals(intervals))
+    return ledger_sum(e - s for s, e in merge_intervals(intervals))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -267,7 +281,7 @@ class PowerTimeline:
     def dynamic_energy_j(self, scheduler: str | None = None) -> float:
         """Sum of per-task dynamic energy, in segment (commit) order —
         identical arithmetic to summing ``PodRecord.energy_j``."""
-        return sum(s.energy_j for s in self._segs(scheduler))
+        return ledger_sum(s.energy_j for s in self._segs(scheduler))
 
     def busy_intervals(self, scheduler: str | None = None
                        ) -> dict[str, list[tuple[float, float]]]:
@@ -281,9 +295,9 @@ class PowerTimeline:
         """Idle (static) energy: each node's idle power x the union time the
         scheduler's tasks keep it awake — the legacy decomposition."""
         classes = {s.node: s.node_class for s in self._segs(scheduler)}
-        return sum(NODE_ENERGY_PROFILES[classes[node]]["idle_power"]
-                   * union_length(ivs)
-                   for node, ivs in self.busy_intervals(scheduler).items())
+        busy = self.busy_intervals(scheduler)
+        return ledger_sum(NODE_ENERGY_PROFILES[classes[node]]["idle_power"]
+                          * union_length(ivs) for node, ivs in busy.items())
 
     def energy_kj(self, scheduler: str | None = None) -> float:
         return (self.dynamic_energy_j(scheduler)
@@ -360,9 +374,9 @@ class PowerTimeline:
         from repro.core.carbon import J_PER_KWH
         self._require_signal()
         sig = self.carbon_signal
-        return sum(p * sig.integral(self.region_of(node), lo, hi)
-                   for lo, hi, p, node in self._power_pieces(scheduler)
-                   ) / J_PER_KWH
+        return ledger_sum(p * sig.integral(self.region_of(node), lo, hi)
+                          for lo, hi, p, node in self._power_pieces(scheduler)
+                          ) / J_PER_KWH
 
     def carbon_series(self, scheduler: str | None = None):
         """Cumulative carbon over time: ``(edges, grams)`` with ``grams[k]``
@@ -396,12 +410,12 @@ class PowerTimeline:
         """Non-task baseline energy from the state ledger: idle power while
         IDLE or WAKING, residual draw while ASLEEP (``state`` filters to one
         state; None sums all). Zero on runs without an AutoscalePolicy."""
-        return sum(iv.energy_j for iv in self.state_intervals
-                   if state is None or iv.state == state)
+        return ledger_sum(iv.energy_j for iv in self.state_intervals
+                          if state is None or iv.state == state)
 
     def wake_transition_energy_j(self) -> float:
         """Total wake-surge energy (one lump per ASLEEP→awake transition)."""
-        return sum(w.energy_j for w in self.wake_transitions)
+        return ledger_sum(w.energy_j for w in self.wake_transitions)
 
     def fleet_idle_energy_kj(self) -> float:
         """Every joule the fleet drew that is not task dynamic power:
@@ -422,11 +436,12 @@ class PowerTimeline:
         from repro.core.carbon import J_PER_KWH
         self._require_signal()
         sig = self.carbon_signal
-        total = sum(iv.power_w * sig.integral(self.region_of(iv.node),
-                                              iv.start_s, iv.end_s)
-                    for iv in self.state_intervals)
-        total += sum(w.energy_j * sig.intensity(self.region_of(w.node), w.t_s)
-                     for w in self.wake_transitions)
+        total = ledger_sum(iv.power_w * sig.integral(self.region_of(iv.node),
+                                                     iv.start_s, iv.end_s)
+                           for iv in self.state_intervals)
+        total += ledger_sum(w.energy_j
+                            * sig.intensity(self.region_of(w.node), w.t_s)
+                            for w in self.wake_transitions)
         return total / J_PER_KWH
 
     def fleet_carbon_g(self) -> float:

@@ -5,7 +5,7 @@ weighting scheme an operator picks, but it only evaluates five fixed
 vectors. This module sweeps the whole trade-off surface instead: generate a
 simplex-lattice grid of weight vectors, score every scheme in ONE fused
 dispatch (``BatchScheduler.select_many_grid`` — the (S, P, N) closeness
-tensor from ``topsis.closeness_grid`` / the weight-grid Pallas kernel),
+tensor from the jax grid round / the weight-grid Pallas kernel),
 collect per-scheme cost metrics (energy / carbon / mean latency /
 unschedulable rate), and filter to the Pareto-optimal set with an exact
 dominance pass. ``FrontierAtlas.dominant_scheme(regime)`` then answers "which

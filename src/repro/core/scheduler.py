@@ -12,11 +12,19 @@ pipeline the paper compares against: filter (PodFitsResources) → score
 Backends (scoring engines, identical semantics — tests assert equivalence):
 
   numpy   — ``topsis.closeness_np``; lowest latency for single decisions
-            (no device dispatch) and the semantic reference.
-  jax     — jitted jnp engine; ``BatchScheduler`` vmaps it over the pod
-            queue (``topsis.batched_closeness``) for throughput.
-  pallas  — the tiled TPU kernel via ``repro.kernels.ops`` (interpret mode
-            on CPU, Mosaic on TPU); for fleets large enough to tile.
+            (no device dispatch) and the semantic reference. A
+            ``BatchScheduler`` call on a table that is not its attached
+            fleet rebuilds the whole (P, N, C) decision tensor: the oracle.
+  jax     — jitted jnp engine; ``BatchScheduler`` scores a queue as one
+            gather from a device-resident per-kind criteria tensor plus
+            the vmapped closeness (``topsis.batched_closeness``).
+  pallas  — the tiled TPU kernels via ``repro.kernels.ops`` (interpret
+            mode on CPU, Mosaic on TPU); ``BatchScheduler`` streams the
+            per-kind tensor through the scalar-prefetch kinds kernel.
+
+The device backends of ``BatchScheduler`` have one scoring round, through
+a :class:`FleetCriteriaCache`: the attached fleet's, kept across rounds,
+or one built for a single call on any other table.
 """
 from __future__ import annotations
 
@@ -248,8 +256,12 @@ def _check_carbon_scheme(scheme: str, carbon_signal) -> None:
 
 
 class FleetCriteriaCache:
-    """Incrementally maintained decision-matrix cache over one attached
-    :class:`~repro.cluster.node.FleetState`.
+    """Incrementally maintained decision-matrix cache over one fleet
+    snapshot: the scheduler's attached :class:`~repro.cluster.node.
+    FleetState`, or a plain :class:`~repro.cluster.node.NodeTable` that a
+    device-backend call scores once (a throwaway cache, synced whole on
+    first use: every kind row is built in full, and the table is never
+    dirty).
 
     The insight that makes the cache cheap: pods come in a handful of
     workload *kinds* (identical ``(cpu, mem, base_time_s)`` request
@@ -263,11 +275,16 @@ class FleetCriteriaCache:
     construction), and the carbon_rate column is refreshed from the cached
     time-invariant power factor whenever decision time moves.
 
+    ``dev`` is the cache's own device-resident float32 mirror of ``mats``
+    (:meth:`sync_device`), which the jax round gathers from; a throwaway
+    cache uploads a mirror of its own and leaves the attached one alone.
+
     Returned matrices/rows are views into the cache: read-only until the
     next :meth:`sync`.
     """
 
-    def __init__(self, fleet: FleetState, carbon_signal: CarbonSignal | None):
+    def __init__(self, fleet: NodeTable,
+                 carbon_signal: CarbonSignal | None):
         self.fleet = fleet
         self.signal = carbon_signal
         self.n_criteria = 6 if carbon_signal is not None else 5
@@ -276,9 +293,11 @@ class FleetCriteriaCache:
         n = len(fleet)
         self.mats = np.zeros((0, n, self.n_criteria))
         self._power_w = np.zeros((0, n))      # carbon power factor per kind
-        self._synced = fleet.version
+        self._tracked = isinstance(fleet, FleetState)
+        self._synced = fleet.version if self._tracked else 0
         self._carbon_now: float | None = None
         self.intensities: np.ndarray | None = None   # (N,) at _carbon_now
+        self.dev = None           # device-resident (K, N, C) float32 mirror
 
     def _kind_of(self, pod: Pod) -> tuple:
         return (pod.cpu, pod.mem, pod.workload.base_time_s)
@@ -301,8 +320,11 @@ class FleetCriteriaCache:
         new kind rows were appended (device mirrors re-upload on growth)."""
         tel = telemetry.active()
         fleet = self.fleet
-        dirty = fleet.modified_since(self._synced)
-        self._synced = fleet.version
+        if self._tracked:
+            dirty = fleet.modified_since(self._synced)
+            self._synced = fleet.version
+        else:
+            dirty = np.zeros(0, dtype=np.int64)
         carbon_moved = False
         if self.signal is not None and now != self._carbon_now:
             self.intensities = np.asarray(
@@ -354,9 +376,42 @@ class FleetCriteriaCache:
             tel.inc("cache_kind_rows_added", value=float(new_kinds))
         return kind_idx, dirty, carbon_moved, grew
 
+    def sync_device(self, dirty: np.ndarray, carbon_moved: bool,
+                    grew: bool):
+        """Mirror the delta :meth:`sync` just returned onto ``dev`` and
+        return it. Growth (a kind first seen — at most once per workload
+        kind per run) re-uploads the whole (K, N, C) tensor; otherwise the
+        dirty node columns are scattered into the donated buffer (idx
+        padded to a power of two with repeats so the scatter trace is
+        shape-stable), and the carbon column is rewritten only when
+        decision time moved. Builds the jitted helpers on first use."""
+        import jax.numpy as jnp
+        _jit_helpers()
+        tel = telemetry.active()
+        if self.dev is None or grew:
+            tel.inc("cache_device_reuploads",
+                    reason="growth" if self.dev is not None else "first")
+            self.dev = jnp.asarray(self.mats.astype(np.float32))
+            tel.inc("scheduler_upload_bytes", value=float(self.dev.nbytes))
+            return self.dev
+        if dirty.size:
+            tel.inc("cache_device_scatters")
+            d_pad = _pow2_pad_len(dirty.size)
+            idx = np.concatenate(
+                [dirty, np.full(d_pad - dirty.size, dirty[0],
+                                dtype=dirty.dtype)])
+            block = self.mats[:, idx, :].astype(np.float32)
+            self.dev = _scatter_node_cols(self.dev,
+                                          *_upload(tel, (idx, block)))
+        if carbon_moved and self.signal is not None:
+            tel.inc("cache_device_carbon_updates")
+            col = self.mats[:, :, -1].astype(np.float32)
+            self.dev = _set_carbon_col(self.dev, *_upload(tel, (col,)))
+        return self.dev
+
 
 def _jit_helpers():
-    """The incremental jax path's jitted helpers, built lazily so importing
+    """The jax round's jitted helpers, built lazily so importing
     the scheduler never pays jax tracing up front."""
     global _scatter_node_cols, _set_carbon_col, _closeness_from_kinds
     global _closeness_grid_from_kinds
@@ -380,8 +435,7 @@ def _jit_helpers():
     @jax.jit
     def _closeness_from_kinds(dev, kind_idx, ws, benefit, valids):
         # gather the per-kind rows and score in ONE dispatch; the closeness
-        # body is topsis.batched_closeness — the same program the
-        # full-rebuild jax path jits, so the two agree on identical inputs
+        # body is topsis.batched_closeness
         return topsis.batched_closeness(dev[kind_idx], ws, benefit,
                                         valids).closeness
 
@@ -411,24 +465,34 @@ def _pow2_pad_len(n: int) -> int:
     return 1 << max(n - 1, 1).bit_length()
 
 
-def _pad_pod_axis(rows: np.ndarray, valid: np.ndarray,
+def _pad_pod_axis(kind_idx: np.ndarray, valid: np.ndarray,
                   ws: np.ndarray | None = None):
     """Pad one round's device scoring inputs along the pod axis to the next
     power of two: jit and Mosaic both cache compiled programs by shape, so
     shrinking retry queues (P, P-1, ...) reuse a compiled program instead
-    of compiling one per queue length. ``rows`` — the (P, N, C) criteria
-    tensor or the (P,) kind index — pads with zeros, ``ws`` with ones, and
-    ``valid`` with all-False rows: padding pods are infeasible everywhere,
-    score -inf, and :func:`_readback` drops them on the host."""
+    of compiling one per queue length. The (P,) kind index pads with kind
+    0, ``ws`` with ones, and ``valid`` with all-False rows: padding pods
+    are infeasible everywhere, score -inf, and :func:`_readback` drops
+    them on the host."""
     pad = _pow2_pad_len(len(valid)) - len(valid)
     if pad:
-        rows = np.concatenate(
-            [rows, np.zeros((pad,) + rows.shape[1:], rows.dtype)])
+        kind_idx = np.concatenate([kind_idx, np.zeros(pad, kind_idx.dtype)])
         valid = np.concatenate(
             [valid, np.zeros((pad, valid.shape[-1]), bool)])
         if ws is not None:
             ws = np.concatenate([ws, np.ones((pad, ws.shape[-1]))])
-    return rows, valid, ws
+    return kind_idx, valid, ws
+
+
+def _valid_mask(pods: Sequence[Pod], table: NodeTable,
+                exclude) -> np.ndarray:
+    """(P, N) scoring validity: capacity fit, minus the engine's
+    ``exclude`` mask ((N,) or (P, N))."""
+    valid = table.fits(np.asarray([p.cpu for p in pods])[:, None],
+                       np.asarray([p.mem for p in pods])[:, None])
+    if exclude is not None:
+        valid = valid & ~np.asarray(exclude, dtype=bool)
+    return valid
 
 
 class GreenPodScheduler:
@@ -559,7 +623,6 @@ class BatchScheduler:
         self.explain = explain
         self.explanations: list[dict] = []
         self._cache: FleetCriteriaCache | None = None
-        self._dev = None          # device-resident (K, N, C) float32 mirror
 
     def attach(self, fleet: FleetState) -> None:
         """Adopt ``fleet`` as a live, delta-maintained snapshot. Scoring
@@ -569,7 +632,6 @@ class BatchScheduler:
         dirty columns are scattered into the donated buffer and a round is
         one fused gather+closeness dispatch."""
         self._cache = FleetCriteriaCache(fleet, self.carbon_signal)
-        self._dev = None
 
     def weights(self, table: NodeTable) -> np.ndarray:
         carbon = self.carbon_signal is not None
@@ -577,6 +639,19 @@ class BatchScheduler:
             return weights_for(self.scheme, carbon=carbon)
         return adaptive_weights(self.scheme, float(np.mean(table.cpu_util)),
                                 carbon=carbon)
+
+    def _cache_for(self, table: NodeTable) -> FleetCriteriaCache | None:
+        """The criteria cache a scoring call on ``table`` runs through: the
+        attached one when ``table`` is the attached fleet; otherwise None
+        on numpy, whose full rebuild is the reference oracle
+        (tests/test_fleet_state.py asserts the two agree bitwise), and a
+        throwaway cache over the snapshot on jax and pallas, so the device
+        backends have one scoring round only."""
+        if self._cache is not None and table is self._cache.fleet:
+            return self._cache
+        if self.backend == "numpy":
+            return None
+        return FleetCriteriaCache(table, self.carbon_signal)
 
     def score_queue(self, pods: Sequence[Pod], nodes,
                     now: float = 0.0, exclude=None) -> np.ndarray:
@@ -587,65 +662,43 @@ class BatchScheduler:
         (sleeping nodes; per-pod deadline-late WAKING nodes), folded into
         the validity mask every backend already honors.
 
-        When ``nodes`` is the attached :class:`FleetState` this takes the
-        incremental path; any other input scores through the full-rebuild
-        path below, which is kept verbatim as the reference oracle
-        (tests/test_fleet_state.py asserts the two agree bitwise)."""
+        Scores go through the per-kind criteria cache of
+        :meth:`_cache_for`, except numpy on a table that is not the
+        attached fleet, which rebuilds the (P, N, C) tensor below."""
         table = _as_table(nodes)
         tel = telemetry.active()
         tel.inc("scheduler_pods_scored", value=float(len(pods)))
-        if self._cache is not None and table is self._cache.fleet:
-            return self._score_queue_incremental(pods, table, now, exclude)
+        cache = self._cache_for(table)
+        if cache is not None:
+            return self._score_queue_kinds(pods, cache, now, exclude)
         labels = {"backend": self.backend, "path": "rebuild"}
         with tel.stage("scheduler_sync", **labels):
             inten = (self.carbon_signal.intensities(table.region, now)
                      if self.carbon_signal is not None else None)
             mats = decision_matrix_batch(pods, table, carbon_intensity=inten)
         with tel.stage("scheduler_mask", **labels):
-            valid = table.fits(np.asarray([p.cpu for p in pods])[:, None],
-                               np.asarray([p.mem for p in pods])[:, None])
-            if exclude is not None:
-                valid = valid & ~np.asarray(exclude, dtype=bool)
+            valid = _valid_mask(pods, table, exclude)
             w = self.weights(table)
             ws = np.broadcast_to(w, (len(pods), w.shape[0]))
-            p = len(pods)
-            if self.backend != "numpy":
-                mats, valid, ws = _pad_pod_axis(mats, valid, ws)
-        if self.backend == "numpy":
-            return topsis.batched_closeness_np(mats, ws, self._benefit, valid)
-        if self.backend == "jax":
-            with tel.stage("scheduler_upload", **labels):
-                args = _upload(tel, (mats, ws, self._benefit, valid))
-            with tel.stage("scheduler_dispatch", **labels):
-                cc = topsis.batched_closeness_cc(*args)
-            return _readback(tel, cc, labels, p)
-        if self.backend == "pallas":
-            from repro.kernels import ops
-            with tel.stage("scheduler_dispatch", **labels):
-                cc = ops.topsis_closeness_batched(
-                    mats, ws, self._benefit, valid=valid)
-            return _readback(tel, cc, labels, p)
-        raise ValueError(f"unknown backend {self.backend!r}; "
-                         f"choose from {BACKENDS}")
+        return topsis.batched_closeness_np(mats, ws, self._benefit, valid)
 
-    def _score_queue_incremental(self, pods: Sequence[Pod],
-                                 fleet: FleetState, now: float,
-                                 exclude) -> np.ndarray:
-        """The one-dispatch round over the attached fleet: sync the
-        per-kind criteria cache (dirty columns only), then score every pod
-        as a row gather — numpy reads zero-copy views, jax gathers from
-        the device-resident mirror, pallas streams kind blocks through the
-        scalar-prefetch kernel."""
+    def _score_queue_kinds(self, pods: Sequence[Pod],
+                           cache: FleetCriteriaCache, now: float,
+                           exclude) -> np.ndarray:
+        """The one-dispatch round: sync the per-kind criteria cache (dirty
+        columns only), then score every pod as a row gather — numpy reads
+        zero-copy views, jax gathers from the cache's device-resident
+        mirror, pallas streams kind blocks through the scalar-prefetch
+        kernel."""
         tel = telemetry.active()
-        labels = {"backend": self.backend, "path": "incremental"}
-        cache = self._cache
+        labels = {"backend": self.backend,
+                  "path": ("incremental" if cache is self._cache
+                           else "rebuild")}
+        fleet = cache.fleet
         with tel.stage("scheduler_sync", **labels):
             kind_idx, dirty, carbon_moved, grew = cache.sync(pods, now)
         with tel.stage("scheduler_mask", **labels):
-            valid = fleet.fits(np.asarray([p.cpu for p in pods])[:, None],
-                               np.asarray([p.mem for p in pods])[:, None])
-            if exclude is not None:
-                valid = valid & ~np.asarray(exclude, dtype=bool)
+            valid = _valid_mask(pods, fleet, exclude)
             w = self.weights(fleet)
             ws = np.broadcast_to(w, (len(pods), w.shape[0]))
             p = len(pods)
@@ -660,12 +713,11 @@ class BatchScheduler:
                                                valid[i]).closeness)
                 for i, k in enumerate(kind_idx)])
         if self.backend == "jax":
-            _jit_helpers()
             with tel.stage("scheduler_upload", **labels):
-                self._sync_device(cache, dirty, carbon_moved, grew)
+                dev = cache.sync_device(dirty, carbon_moved, grew)
                 args = _upload(tel, (kind_idx, ws, self._benefit, valid))
             with tel.stage("scheduler_dispatch", **labels):
-                cc = _closeness_from_kinds(self._dev, *args)
+                cc = _closeness_from_kinds(dev, *args)
             return _readback(tel, cc, labels, p)
         if self.backend == "pallas":
             from repro.kernels import ops
@@ -676,36 +728,6 @@ class BatchScheduler:
             return _readback(tel, cc, labels, p)
         raise ValueError(f"unknown backend {self.backend!r}; "
                          f"choose from {BACKENDS}")
-
-    def _sync_device(self, cache: FleetCriteriaCache, dirty: np.ndarray,
-                     carbon_moved: bool, grew: bool) -> None:
-        """Mirror this round's cache delta onto the device tensor. Growth
-        (a kind first seen — at most once per workload kind per run)
-        re-uploads the whole (K, N, C) tensor; otherwise the dirty node
-        columns are scattered into the donated buffer (idx padded to a
-        power of two with repeats so the scatter trace is shape-stable),
-        and the carbon column is rewritten only when decision time moved."""
-        import jax.numpy as jnp
-        tel = telemetry.active()
-        if self._dev is None or grew:
-            tel.inc("cache_device_reuploads",
-                    reason="growth" if self._dev is not None else "first")
-            self._dev = jnp.asarray(cache.mats.astype(np.float32))
-            tel.inc("scheduler_upload_bytes", value=float(self._dev.nbytes))
-            return
-        if dirty.size:
-            tel.inc("cache_device_scatters")
-            d_pad = _pow2_pad_len(dirty.size)
-            idx = np.concatenate(
-                [dirty, np.full(d_pad - dirty.size, dirty[0],
-                                dtype=dirty.dtype)])
-            block = cache.mats[:, idx, :].astype(np.float32)
-            self._dev = _scatter_node_cols(self._dev,
-                                           *_upload(tel, (idx, block)))
-        if carbon_moved and self.carbon_signal is not None:
-            tel.inc("cache_device_carbon_updates")
-            col = cache.mats[:, :, -1].astype(np.float32)
-            self._dev = _set_carbon_col(self._dev, *_upload(tel, (col,)))
 
     def _weight_grid(self, schemes) -> np.ndarray:
         """Resolve ``schemes`` — a sequence of scheme names or an (S, C)
@@ -745,60 +767,44 @@ class BatchScheduler:
         ``now`` / ``exclude`` behave exactly as in :meth:`score_queue`;
         the feasibility mask is scheme-independent and shared.
 
-        When ``nodes`` is the attached :class:`FleetState` this takes the
-        incremental path — dirty-column sync plus (jax) one fused
-        gather+grid-closeness dispatch against the device-resident kind
-        tensor, with no re-upload per scheme."""
+        Like :meth:`score_queue`, every call but numpy on a table that is
+        not the attached fleet goes through the per-kind criteria cache —
+        on jax one fused gather+grid-closeness dispatch against the
+        cache's device-resident kind tensor, with no re-upload per
+        scheme."""
         table = _as_table(nodes)
         ws = self._weight_grid(schemes)
         tel = telemetry.active()
         tel.inc("scheduler_pods_scored", value=float(len(pods)))
-        if self._cache is not None and table is self._cache.fleet:
-            return self._score_grid_incremental(pods, table, ws, now,
-                                                exclude)
+        cache = self._cache_for(table)
+        if cache is not None:
+            return self._score_grid_kinds(pods, cache, ws, now, exclude)
         labels = {"backend": self.backend, "path": "rebuild"}
         with tel.stage("scheduler_sync", **labels):
             inten = (self.carbon_signal.intensities(table.region, now)
                      if self.carbon_signal is not None else None)
             mats = decision_matrix_batch(pods, table, carbon_intensity=inten)
         with tel.stage("scheduler_mask", **labels):
-            valid = table.fits(np.asarray([p.cpu for p in pods])[:, None],
-                               np.asarray([p.mem for p in pods])[:, None])
-            if exclude is not None:
-                valid = valid & ~np.asarray(exclude, dtype=bool)
-        if self.backend == "numpy":
-            return topsis.closeness_grid_np(mats, ws, self._benefit, valid)
-        if self.backend == "jax":
-            with tel.stage("scheduler_dispatch", **labels):
-                cc = topsis.closeness_grid(mats, ws, self._benefit, valid)
-            return _readback(tel, cc, labels)
-        if self.backend == "pallas":
-            from repro.kernels import ops
-            with tel.stage("scheduler_dispatch", **labels):
-                cc = ops.topsis_closeness_grid(mats, ws, self._benefit,
-                                               valid=valid)
-            return _readback(tel, cc, labels)
-        raise ValueError(f"unknown backend {self.backend!r}; "
-                         f"choose from {BACKENDS}")
+            valid = _valid_mask(pods, table, exclude)
+        return topsis.closeness_grid_np(mats, ws, self._benefit, valid)
 
-    def _score_grid_incremental(self, pods: Sequence[Pod],
-                                fleet: FleetState, ws: np.ndarray,
-                                now: float, exclude) -> np.ndarray:
-        """Grid round over the attached fleet: one dirty-column sync, then
-        the per-backend (S, P, N) scoring — numpy loops scheme x pod over
-        the zero-copy cache views (the reference), jax fuses gather + grid
-        closeness into one dispatch on the device mirror, pallas streams
-        the (P, N, C) gather through the weight-grid kernel."""
+    def _score_grid_kinds(self, pods: Sequence[Pod],
+                          cache: FleetCriteriaCache, ws: np.ndarray,
+                          now: float, exclude) -> np.ndarray:
+        """Grid round through the per-kind cache: one dirty-column sync,
+        then the per-backend (S, P, N) scoring — numpy loops scheme x pod
+        over the zero-copy cache views (the reference), jax fuses gather +
+        grid closeness into one dispatch on the device mirror, pallas
+        streams the (P, N, C) gather through the weight-grid kernel."""
         tel = telemetry.active()
-        labels = {"backend": self.backend, "path": "incremental"}
-        cache = self._cache
+        labels = {"backend": self.backend,
+                  "path": ("incremental" if cache is self._cache
+                           else "rebuild")}
+        fleet = cache.fleet
         with tel.stage("scheduler_sync", **labels):
             kind_idx, dirty, carbon_moved, grew = cache.sync(pods, now)
         with tel.stage("scheduler_mask", **labels):
-            valid = fleet.fits(np.asarray([p.cpu for p in pods])[:, None],
-                               np.asarray([p.mem for p in pods])[:, None])
-            if exclude is not None:
-                valid = valid & ~np.asarray(exclude, dtype=bool)
+            valid = _valid_mask(pods, fleet, exclude)
             p = len(pods)
             if self.backend == "jax":
                 kind_idx, valid, _ = _pad_pod_axis(kind_idx, valid)
@@ -811,12 +817,11 @@ class BatchScheduler:
                     for i, k in enumerate(kind_idx)])
                 for w in ws])
         if self.backend == "jax":
-            _jit_helpers()
             with tel.stage("scheduler_upload", **labels):
-                self._sync_device(cache, dirty, carbon_moved, grew)
+                dev = cache.sync_device(dirty, carbon_moved, grew)
                 args = _upload(tel, (kind_idx, ws, self._benefit, valid))
             with tel.stage("scheduler_dispatch", **labels):
-                cc = _closeness_grid_from_kinds(self._dev, *args)
+                cc = _closeness_grid_from_kinds(dev, *args)
             return _readback(tel, cc, labels, p)
         if self.backend == "pallas":
             from repro.kernels import ops
@@ -871,10 +876,7 @@ class BatchScheduler:
             inten = (self.carbon_signal.intensities(table.region, now)
                      if self.carbon_signal is not None else None)
             mats = decision_matrix_batch(pods, table, carbon_intensity=inten)
-        valid = table.fits(np.asarray([p.cpu for p in pods])[:, None],
-                           np.asarray([p.mem for p in pods])[:, None])
-        if exclude is not None:
-            valid = valid & ~np.asarray(exclude, dtype=bool)
+        valid = _valid_mask(pods, table, exclude)
         w = self.weights(table)
         for i, (pod, idx) in enumerate(zip(pods, assignments)):
             exp = topsis.explain_np(mats[i], w, self._benefit, valid[i],

@@ -111,14 +111,6 @@ def select(matrix: jax.Array, weights: jax.Array, benefit: jax.Array,
 # Batched form: P concurrent pods, each with its own (N, C) matrix + weights.
 batched_closeness = jax.vmap(closeness, in_axes=(0, 0, None, 0))
 
-@jax.jit
-def batched_closeness_cc(mats, ws, benefit, valids):
-    """Closeness coefficients only, (P, N). Returning just the scores lets
-    XLA drop the ranking sort and the (P, N, C) weighted tensor from the
-    program — at N=8k the scheduler only reads closeness, and hauling the
-    full TopsisResult back to host dominates the batch runtime."""
-    return batched_closeness(mats, ws, benefit, valids).closeness
-
 
 def batched_closeness_np(mats, ws, benefit, valids=None) -> "np.ndarray":
     """(P, N) closeness via a per-pod :func:`closeness_np` loop — the
@@ -130,39 +122,14 @@ def batched_closeness_np(mats, ws, benefit, valids=None) -> "np.ndarray":
     return np.stack(out, axis=0)
 
 
-# --- Weight-scheme grid: one dispatch over (S schemes x P pods x N nodes) ---
-@jax.jit
-def _closeness_grid_jit(mats, ws, benefit, valids):
-    def one_scheme(w):
-        wp = jnp.broadcast_to(w, (mats.shape[0], w.shape[-1]))
-        return batched_closeness(mats, wp, benefit, valids).closeness
-    return jax.vmap(one_scheme)(ws)
-
-
-def closeness_grid(mats: jax.Array, ws: jax.Array, benefit: jax.Array,
-                   valids: jax.Array | None = None) -> jax.Array:
-    """(S, P, N) closeness for a (P, N, C) queue tensor under an (S, C)
-    weight-scheme grid — :func:`closeness` vmapped over the scheme axis and
-    jitted as ONE program, so sweeping thousands of weighting schemes costs
-    one dispatch instead of S (the Pareto-frontier scoring path,
-    ``repro.core.pareto``). Row ``s`` computes exactly what
-    :func:`batched_closeness` computes for ``ws[s]``: the (P, N, C)
-    normalization is weight-independent and is shared across schemes by XLA,
-    while ideal points and distances are per scheme. ``valids`` is the
-    usual (P, N) feasibility mask (shared by all schemes; invalid -> -inf).
-    """
-    if valids is None:
-        # all-true mask is bitwise inert (masked ideal points and the -inf
-        # fill both reduce to the unmasked pipeline) and keeps one trace
-        valids = jnp.ones(mats.shape[:2], dtype=bool)
-    return _closeness_grid_jit(mats, ws, benefit, valids)
-
-
+# --- Weight-scheme grid: (S schemes x P pods x N nodes) ---
 def closeness_grid_np(mats, ws, benefit, valids=None) -> "np.ndarray":
-    """(S, P, N) numpy reference for :func:`closeness_grid`: a per-scheme
-    loop of :func:`batched_closeness_np`, so row ``s`` is bitwise equal to
-    scoring the queue under ``ws[s]`` alone — the oracle the jax and pallas
-    grid paths are verified against (1e-5, float32 device math)."""
+    """(S, P, N) closeness for a (P, N, C) queue tensor under an (S, C)
+    weight-scheme grid: a per-scheme loop of :func:`batched_closeness_np`,
+    so row ``s`` is bitwise equal to scoring the queue under ``ws[s]``
+    alone — the oracle the jax and pallas grid rounds of
+    ``BatchScheduler.score_queue_grid`` are verified against (1e-5,
+    float32 device math)."""
     import numpy as np
     ws = np.asarray(ws, dtype=np.float64)
     p = len(mats)

@@ -100,52 +100,6 @@ def topsis_closeness(matrix: jax.Array, weights: jax.Array,
     return cc
 
 
-def topsis_closeness_batched(mats: jax.Array, weights: jax.Array,
-                             benefit: jax.Array, *,
-                             valid: jax.Array | None = None,
-                             block_n: int | None = None,
-                             interpret: bool | None = None) -> jax.Array:
-    """(P, N) closeness for a (P, N, C) queue tensor; C <= 8 (5 paper
-    criteria or 6 with the carbon-rate column, both under C_PAD).
-
-    The fleet-scale batch path: per-pod column norms and ideal points are
-    global reductions in XLA; the Pallas kernel streams the (pods x node
-    blocks) grid. ``weights`` is (C,) shared or (P, C) per pod; ``valid`` an
-    optional (P, N) feasibility mask (excluded from ideals, -inf in the
-    result, as in the single-matrix form).
-    """
-    interpret = resolve_interpret(interpret)
-    mats = jnp.asarray(mats).astype(jnp.float32)
-    p, n, c = mats.shape
-    assert c <= _tp.C_PAD, f"at most {_tp.C_PAD} criteria, got {c}"
-    benefit = jnp.asarray(benefit, bool)
-    if valid is not None:
-        valid = jnp.asarray(valid, bool)
-    w = jnp.asarray(weights, jnp.float32)
-    if w.ndim == 1:
-        w = jnp.broadcast_to(w, (p, c))
-    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), _EPS)
-    norms = jnp.sqrt(jnp.sum(mats * mats, axis=1))            # (P, C)
-    inv_norm = 1.0 / jnp.maximum(norms, _EPS)
-    v = mats * inv_norm[:, None, :] * w[:, None, :]
-    a_pos, a_neg = _topsis.masked_ideal_points(v, benefit, valid)  # (P, C)
-
-    if block_n is None:
-        block_n = _auto_block_n(n)
-    xt = _pad_to(_pad_to(mats.transpose(0, 2, 1), 1, _tp.C_PAD), 2, block_n)
-
-    def col(x):  # (P, C) -> (P, C_PAD, 1)
-        return _pad_to(x.astype(jnp.float32), 1, _tp.C_PAD)[:, :, None]
-
-    cc = _tp.topsis_closeness_batched_blocks(
-        xt, col(inv_norm), col(w), col(a_pos), col(a_neg),
-        block_n=block_n, interpret=interpret)
-    cc = cc[:, 0, :n]
-    if valid is not None:
-        cc = jnp.where(valid, cc, -jnp.inf)
-    return cc
-
-
 def topsis_closeness_grid(mats: jax.Array, weights: jax.Array,
                           benefit: jax.Array, *,
                           valid: jax.Array | None = None,
@@ -159,7 +113,7 @@ def topsis_closeness_grid(mats: jax.Array, weights: jax.Array,
     criteria node-block is fetched from HBM once and reused across all S
     schemes (see ``topsis_pallas.topsis_closeness_grid_blocks``). ``valid``
     is the usual (P, N) feasibility mask, shared by every scheme; row
-    semantics match ``repro.core.topsis.closeness_grid``.
+    semantics match ``repro.core.topsis.closeness_grid_np``.
     """
     interpret = resolve_interpret(interpret)
     mats = jnp.asarray(mats).astype(jnp.float32)
@@ -214,8 +168,10 @@ def topsis_closeness_kinds(mats_kinds: jax.Array, kind_idx: jax.Array,
     Per-pod column norms are gathered from per-kind norms — bitwise equal
     to the per-pod reduction because each pod's rows ARE its kind's rows.
     Ideal points stay per pod (``valid`` differs pod to pod) and run in
-    XLA; ``weights`` is (C,) shared or (P, C) per pod; result semantics
-    (invalid -> -inf) match :func:`topsis_closeness_batched`.
+    XLA; ``weights`` is (C,) shared or (P, C) per pod; ``valid`` is an
+    optional (P, N) feasibility mask (excluded from the ideals, -inf in
+    the result, as in :func:`topsis_closeness`). With ``kind_idx =
+    arange(P)`` it scores a plain (P, N, C) queue tensor.
     """
     interpret = resolve_interpret(interpret)
     mats_kinds = jnp.asarray(mats_kinds).astype(jnp.float32)

@@ -69,49 +69,6 @@ def topsis_closeness_blocks(xt: jax.Array, inv_norm: jax.Array, w: jax.Array,
     )(xt, inv_norm, w, a_pos, a_neg)
 
 
-def _topsis_batched_kernel(xt_ref, inv_norm_ref, w_ref, a_pos_ref, a_neg_ref,
-                           cc_ref):
-    """One (pod, node-block) grid cell: xt (1, C_PAD, BLOCK_N) raw criteria
-    for pod p; per-pod small operands (1, C_PAD, 1); out cc (1, 1, BLOCK_N).
-    Same math as :func:`_topsis_kernel` with the pod axis leading — the
-    criteria reduction stays a sublane reduction (axis=1)."""
-    xt = xt_ref[...].astype(jnp.float32)
-    v = xt * inv_norm_ref[...] * w_ref[...]
-    dp = v - a_pos_ref[...]
-    dn = v - a_neg_ref[...]
-    d_pos = jnp.sqrt(jnp.sum(dp * dp, axis=1, keepdims=True))
-    d_neg = jnp.sqrt(jnp.sum(dn * dn, axis=1, keepdims=True))
-    denom = d_pos + d_neg
-    cc = d_neg / jnp.maximum(denom, _EPS)
-    cc_ref[...] = jnp.where(denom <= _EPS, 0.5, cc)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def topsis_closeness_batched_blocks(xt: jax.Array, inv_norm: jax.Array,
-                                    w: jax.Array, a_pos: jax.Array,
-                                    a_neg: jax.Array,
-                                    block_n: int = DEFAULT_BLOCK_N,
-                                    interpret: bool = False) -> jax.Array:
-    """Whole-queue scoring: xt (P, C_PAD, N_pad) with N_pad % block_n == 0;
-    per-pod small operands (P, C_PAD, 1). Grid is (pods, node blocks);
-    returns (P, 1, N_pad) closeness coefficients."""
-    p, c_pad, n_pad = xt.shape
-    assert c_pad == C_PAD and n_pad % block_n == 0, (xt.shape, block_n)
-    grid = (p, n_pad // block_n)
-    small = pl.BlockSpec((1, C_PAD, 1), lambda b, i: (b, 0, 0))
-    return pl.pallas_call(
-        _topsis_batched_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, C_PAD, block_n), lambda b, i: (b, 0, i)),
-            small, small, small, small,
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_n), lambda b, i: (b, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((p, 1, n_pad), jnp.float32),
-        interpret=interpret,
-    )(xt, inv_norm, w, a_pos, a_neg)
-
-
 def _topsis_grid_kernel(xt_ref, inv_norm_ref, w_ref, a_pos_ref, a_neg_ref,
                         cc_ref):
     """One (pod, node-block, scheme) grid cell of the weight-grid form:
@@ -119,7 +76,8 @@ def _topsis_grid_kernel(xt_ref, inv_norm_ref, w_ref, a_pos_ref, a_neg_ref,
     its BlockSpec index map ignores the scheme coordinate and the pipeline
     keeps the block resident across all S schemes; per-(scheme, pod) small
     operands (1, 1, C_PAD, 1); out cc (1, 1, 1, BLOCK_N). Math is
-    :func:`_topsis_batched_kernel` with the scheme block-dim stripped."""
+    :func:`_topsis_kernel` with the pod axis leading (the criteria
+    reduction is over axis 1) and the scheme block-dim stripped."""
     xt = xt_ref[...].astype(jnp.float32)
     v = xt * inv_norm_ref[...] * w_ref[0]
     dp = v - a_pos_ref[0]
@@ -175,9 +133,10 @@ def _topsis_kinds_kernel(kind_ref, xt_ref, inv_norm_ref, w_ref, a_pos_ref,
     scalar-prefetch ``kind_ref`` steered this pod's criteria block — the
     BlockSpec index map reads ``kind_ref[b]`` — so ``xt_ref`` holds the
     (1, C_PAD, BLOCK_N) block of the pod's *workload kind*, not a per-pod
-    copy. Math is identical to :func:`_topsis_batched_kernel`; the small
-    operands stay per pod (each pod's feasibility mask shapes its ideal
-    points even when the raw criteria rows are shared)."""
+    copy. Math is :func:`_topsis_kernel` with the pod axis leading (the
+    criteria reduction is over axis 1); the small operands stay per pod
+    (each pod's feasibility mask shapes its ideal points even when the raw
+    criteria rows are shared)."""
     del kind_ref       # consumed by the index maps, not the kernel body
     xt = xt_ref[...].astype(jnp.float32)
     v = xt * inv_norm_ref[...] * w_ref[...]

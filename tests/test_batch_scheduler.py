@@ -106,8 +106,8 @@ def test_pallas_batched_matches_closeness_np():
     valid = rng.uniform(size=(p, n)) < 0.7
     valid[:, 0] = True
     want = topsis.batched_closeness_np(mats, ws, BENEFIT, valid)
-    got = np.asarray(ops.topsis_closeness_batched(mats, ws, BENEFIT,
-                                                  valid=valid))
+    got = np.asarray(ops.topsis_closeness_kinds(mats, np.arange(p), ws,
+                                                BENEFIT, valid=valid))
     np.testing.assert_allclose(got[valid], want[valid], atol=1e-5)
     assert np.all(np.isneginf(got[~valid]))
 
@@ -116,8 +116,8 @@ def test_pallas_degenerate_all_equal():
     M = np.ones((16, 5))
     got = np.asarray(ops.topsis_closeness(M, np.ones(5), BENEFIT))
     np.testing.assert_allclose(got, 0.5, atol=1e-6)
-    batched = np.asarray(ops.topsis_closeness_batched(
-        np.ones((3, 16, 5)), np.ones(5), BENEFIT))
+    batched = np.asarray(ops.topsis_closeness_kinds(
+        np.ones((3, 16, 5)), np.arange(3), np.ones(5), BENEFIT))
     np.testing.assert_allclose(batched, 0.5, atol=1e-6)
 
 
@@ -155,12 +155,12 @@ def test_batch_scheduler_scores_match_numpy(backend):
                          ids=["rebuild", "incremental"])
 def test_pallas_shrinking_queues_share_one_kernel(attached):
     """The Pallas rounds pad the pod axis to a power of two, as the jax
-    rounds do: queues of 8, 7, 6 and 5 pods reuse one compiled kernel, and
-    every round still matches the numpy reference."""
+    rounds do: queues of 8, 7, 6 and 5 pods reuse one compiled kinds
+    kernel, on the attached fleet and on a fleet that is not attached
+    alike, and every round still matches the numpy reference."""
     from repro.cluster.node import FleetState, make_fleet_nodes
     from repro.kernels import topsis_pallas as tp
-    kernel = (tp.topsis_closeness_kinds_blocks if attached
-              else tp.topsis_closeness_batched_blocks)
+    kernel = tp.topsis_closeness_kinds_blocks
     fleet = FleetState.from_nodes(make_fleet_nodes(100, seed=3,
                                                    utilization=0.4))
     sched = BatchScheduler("energy_centric", backend="pallas")
@@ -529,8 +529,9 @@ def backend_compiles():
 def test_unpadded_queue_lengths_compile_nothing_new(backend, attached,
                                                     backend_compiles,
                                                     monkeypatch):
-    """Once each padded queue length has been scored, queues of any real
-    length up to it compile no new program: the padding rows are dropped
+    """Once each padded queue length has been scored at each count of
+    distinct pod kinds, queues of any real length up to it compile no new
+    program: the padding rows are dropped
     after the readback, on the host. The (p, N) scores are the first p rows
     of the padded device result bit for bit, in a contiguous array of their
     own, and match the numpy reference."""
@@ -549,10 +550,14 @@ def test_unpadded_queue_lengths_compile_nothing_new(backend, attached,
                        for i in range(p)]
     lengths = (1, 3, 37, 255, 257)
     # every pod kind first, so that the kind cache holds them all while
-    # each padded length compiles
+    # each padded length compiles; a table that is not attached is scored
+    # through a cache of its own per call, whose kind count is part of the
+    # program's shape, so each padded length compiles at every count
     sched.score_queue(queue(len(kinds)), table)
     for pad in sorted({scheduler_mod._pow2_pad_len(p) for p in lengths}):
-        sched.score_queue(queue(pad), table)
+        for k in range(1, len(kinds) + 1):
+            sched.score_queue([Pod(i, kinds[i % k], "topsis")
+                               for i in range(pad)], table)
     padded = []
     readback = scheduler_mod._readback
 

@@ -56,14 +56,6 @@ def _single(shape):
         interpret=False)
 
 
-def _batched(shape):
-    from repro.kernels import topsis_pallas as tp
-    small = shape((P, C_PAD, 1))
-    return tp.topsis_closeness_batched_blocks.lower(
-        shape((P, C_PAD, N)), small, small, small, small, block_n=BLOCK,
-        interpret=False)
-
-
 def _kinds(shape, k=3):
     from repro.kernels import topsis_pallas as tp
     small = shape((P, C_PAD, 1))
@@ -80,8 +72,8 @@ def _grid(shape, s=512, n=1_024):
         per_scheme, block_n=n, interpret=False)
 
 
-@pytest.mark.parametrize("lower", [_single, _batched, _kinds, _grid],
-                         ids=["single", "batched", "kinds", "grid"])
+@pytest.mark.parametrize("lower", [_single, _kinds, _grid],
+                         ids=["single", "kinds", "grid"])
 def test_pallas_kernel_compiles_for_v5e(shape, lower):
     compiled = lower(shape).compile()
     assert "tpu_custom_call" in compiled.as_text()

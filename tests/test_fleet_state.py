@@ -41,6 +41,7 @@ except ModuleNotFoundError:
 from repro.cluster.engine import EventEngine, SimState
 from repro.cluster.node import FleetState, NodeTable, make_fleet_nodes
 from repro.cluster.workload import WORKLOADS, ArrivalProcess, Pod
+from repro.core import telemetry
 from repro.core.carbon import diurnal_fleet_signal
 from repro.core.elastic import ASLEEP, IDLE
 from repro.core.energy import PowerTimeline
@@ -224,6 +225,48 @@ def test_incremental_carbon_column_tracks_decision_time(backend):
             np.testing.assert_allclose(cc_inc[finite], cc_ref[finite],
                                        atol=1e-5, rtol=0)
         fs.release(3, 0.5, 1.0)
+
+
+def test_detached_jax_calls_leave_the_attached_mirror_alone():
+    """A jax call on a table that is not the attached fleet runs the same
+    kind-gather round on a cache of its own. Interleaved with attached
+    rounds, both kinds of call stay within 1e-5 of numpy, and the attached
+    cache re-uploads its device mirror exactly as often as it does without
+    them: each detached call uploads once, for its own cache."""
+    def run(detached: bool):
+        fs = FleetState.from_nodes(make_fleet_nodes(24, seed=4,
+                                                    utilization=0.3))
+        other = NodeTable.from_nodes(make_fleet_nodes(24, seed=5,
+                                                      utilization=0.5))
+        sched = BatchScheduler("energy_centric", backend="jax")
+        sched.attach(fs)
+        ref = BatchScheduler("energy_centric", backend="numpy")
+        pods = _queue()
+        calls = 0
+        with telemetry.enabled() as tel:
+            for step in range(4):
+                fs.bind(step, 0.25, 0.5)
+                tables = [other, fs, other] if detached else [fs]
+                for table in tables:
+                    snapshot = (NodeTable.from_nodes(fs.nodes)
+                                if table is fs else other)
+                    want = ref.score_queue(pods, snapshot)
+                    got = sched.score_queue(pods, table)
+                    calls += table is other
+                    finite = np.isfinite(want)
+                    np.testing.assert_array_equal(finite, np.isfinite(got))
+                    np.testing.assert_allclose(got[finite], want[finite],
+                                               atol=1e-5, rtol=0)
+            uploads = (tel.counter_value("cache_device_reuploads",
+                                         reason="first")
+                       + tel.counter_value("cache_device_reuploads",
+                                           reason="growth"))
+        cache = sched._cache
+        np.testing.assert_array_equal(np.asarray(cache.dev),
+                                      cache.mats.astype(np.float32))
+        return uploads - calls
+
+    assert run(detached=True) == run(detached=False) == 1
 
 
 def test_attached_per_pod_scheduler_matches_detached():

@@ -1,6 +1,6 @@
 """Pareto frontier engine: weight grids, fused grid scoring, dominance.
 
-Pins the tentpole equivalences: ``closeness_grid`` row ``s`` is bitwise
+Pins the tentpole equivalences: grid row ``s`` is bitwise
 (numpy) / 1e-5 (jax, pallas) equal to scoring the queue under ``ws[s]``
 alone; the paper's named schemes come back as a grid special case with
 placements identical to per-scheme ``select_many``; and the dominance
@@ -141,6 +141,15 @@ def test_scheduler_rejects_unnormalized_grid():
 
 
 # --- closeness_grid equivalence ----------------------------------------------
+def device_grid(mats, ws, valids):
+    """The jax grid round's program on a plain (P, N, C) tensor, each pod
+    its own kind."""
+    from repro.core import scheduler
+    scheduler._jit_helpers()
+    return np.asarray(scheduler._closeness_grid_from_kinds(
+        mats, np.arange(len(mats)), ws, BENEFIT5, valids))
+
+
 def test_closeness_grid_np_rows_bitwise():
     mats, ws, valids = rand_grid_inputs(4, 23, 6, seed=0)
     grid = topsis.closeness_grid_np(mats, ws, BENEFIT5, valids)
@@ -160,7 +169,7 @@ def test_closeness_grid_matches_reference(backend):
     mats, ws, valids = rand_grid_inputs(3, 37, 5, seed=1)
     want = topsis.closeness_grid_np(mats, ws, BENEFIT5, valids)
     if backend == "jax":
-        got = np.asarray(topsis.closeness_grid(mats, ws, BENEFIT5, valids))
+        got = device_grid(mats, ws, valids)
     else:
         from repro.kernels import ops
         got = np.asarray(ops.topsis_closeness_grid(mats, ws, BENEFIT5,
@@ -171,13 +180,16 @@ def test_closeness_grid_matches_reference(backend):
 
 
 def test_closeness_grid_no_mask_matches_masked_alltrue():
+    from repro.kernels import ops
     mats, ws, _ = rand_grid_inputs(2, 9, 3, seed=2)
-    a = np.asarray(topsis.closeness_grid(mats, ws, BENEFIT5))
-    b = np.asarray(topsis.closeness_grid(mats, ws, BENEFIT5,
-                                         np.ones((2, 9), bool)))
+    alltrue = np.ones((2, 9), bool)
+    a = np.asarray(ops.topsis_closeness_grid(mats, ws, BENEFIT5))
+    b = np.asarray(ops.topsis_closeness_grid(mats, ws, BENEFIT5,
+                                             valid=alltrue))
     assert np.array_equal(a, b)
     ref = topsis.closeness_grid_np(mats, ws, BENEFIT5)
     assert np.max(np.abs(a - ref)) < 1e-5
+    assert np.max(np.abs(device_grid(mats, ws, alltrue) - ref)) < 1e-5
 
 
 @settings(max_examples=15, deadline=None)
@@ -194,8 +206,7 @@ def test_grid_row_property(s, seed):
             want[si],
             topsis.batched_closeness_np(
                 mats, np.broadcast_to(ws[si], (3, 5)), BENEFIT5, valids))
-    for got in (np.asarray(topsis.closeness_grid(mats, ws, BENEFIT5,
-                                                 valids)),
+    for got in (device_grid(mats, ws, valids),
                 np.asarray(ops.topsis_closeness_grid(mats, ws, BENEFIT5,
                                                      valid=valids))):
         finite = np.isfinite(want)

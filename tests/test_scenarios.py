@@ -138,6 +138,17 @@ def test_power_timeline_direct():
     assert tl.energy_kj("default") == 0.0
 
 
+def test_ledger_totals_are_the_commit_order_fold():
+    """Totals add the segments left to right in commit order: 1e16 + 1.0
+    rounds back to 1e16, so the fold gives 0.0. A compensated sum (the
+    built-in ``sum()`` of floats since Python 3.12) would give 1.0."""
+    tl = PowerTimeline()
+    for power in (1e16, 1.0, -1e16):
+        tl.add("n0", "A", "topsis", 0.0, 1.0, power)
+    assert [s.energy_j for s in tl.segments] == [1e16, 1.0, -1e16]
+    assert tl.dynamic_energy_j() == 0.0
+
+
 # --- Poisson scenarios -------------------------------------------------------
 def test_poisson_scenario_end_to_end():
     """Poisson bursts on a mixed fleet: every pod accounted for, placements
